@@ -19,6 +19,8 @@ import argparse
 import json
 import time
 
+from repro.launch.persistent_cache import enable_persistent_cache
+
 from . import (
     bench_bounds,
     bench_calibration,
@@ -85,6 +87,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write results as a JSON artifact")
     args = ap.parse_args()
+    enable_persistent_cache()
     common.set_pipeline(args.pipeline)
     common.set_backend(args.backend)
     meta = {"pipeline": args.pipeline, "smoke": args.smoke,

@@ -42,7 +42,7 @@ class BackendStats:
     h2d: int = 0            # host -> device commits (from_host)
     d2h: int = 0            # device -> host gathers (to_host)
     device_moves: int = 0   # device -> device operand moves
-    fallbacks: int = 0      # ops executed via the numpy fallback path
+    fallbacks: int = 0      # ops run on the host: 0, a missing lowering raises
     replays: int = 0        # lineage-replay re-executions (fault recovery)
 
     def reset(self) -> None:
@@ -75,7 +75,7 @@ class BlockBackend:
         self.dtype = dtype
         self.stats = BackendStats()
         # flight recorder (core.trace): when set, compiled backends record
-        # compile-cache hits/misses and fallbacks at dispatch time
+        # compile-cache hits/misses at dispatch time
         self.tracer = None
 
     # -- storage ------------------------------------------------------------

@@ -29,7 +29,7 @@ kind            emitted at
 ``node_death``  node killed mid-drain (``args["lost"]`` blocks dropped)
 ``replay``      lineage replay re-executed a lost block
 ``plan_hit``/``plan_miss``      plan-cache lookup outcome
-``compile_hit``/``compile_miss``/``fallback``  structural kernel cache
+``compile_hit``/``compile_miss``  structural kernel cache
 ==============  ==========================================================
 
 Times ``t0``/``t1`` are *simulated* seconds on the event's clock track

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ops import CompilerParams
 
 NEG_INF = -1e30
 
@@ -134,7 +133,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 128), jnp.float32),   # running denom
             pltpu.VMEM((bq, hd), jnp.float32),    # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ops import CompilerParams
-
 from .flash_attention import NEG_INF, flash_attention_pallas
 
 
@@ -189,7 +187,7 @@ def _vjp_bwd(causal, window, q_offset, bq, bk, interpret, res, dout):
                    jax.ShapeDtypeStruct((B * KV, Skv, hd), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk_, hd), jnp.float32),
                         pltpu.VMEM((bk_, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, kf, vf, dog, og, lseg)
@@ -220,7 +218,7 @@ def _vjp_bwd(causal, window, q_offset, bq, bk, interpret, res, dout):
         out_specs=pl.BlockSpec((1, bq_, hd), lambda b, iq, ik: (b, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq_, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, dof, of, lsef)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ops import CompilerParams
-
 
 def _scan_kernel(dA_ref, dBx_ref, c_ref, y_ref, h_ref, *, chunk: int):
     ic = pl.program_id(2)
@@ -66,7 +64,7 @@ def mamba_scan_pallas(
         out_specs=pl.BlockSpec((1, chunk, bd), lambda b, d, c: (b, c, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, DI), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

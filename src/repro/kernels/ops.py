@@ -1,9 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
 Each op pads inputs up to block multiples, dispatches the kernel, and slices
-the result back; ``interpret`` defaults to True off-TPU so the same call
-sites run everywhere (CPU tests exercise the kernel bodies in interpret
-mode; on TPU the compiled kernels run natively).
+the result back.  On a TPU the kernels always compile to Mosaic; elsewhere
+``interpret`` defaults to True so the same call sites run on the CPU (tests
+exercise the kernel bodies in interpret mode).
 """
 from __future__ import annotations
 
@@ -12,18 +12,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
-
-# Version-compat shim: jax renamed TPUCompilerParams -> CompilerParams (and
-# back) across releases.  Every Pallas kernel imports the name from here; the
-# kernel modules are imported lazily below (at trace time) so they can.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret(requested: Optional[bool]) -> bool:
+    """Interpret mode only off the TPU: on a TPU a kernel never runs
+    interpreted, whatever the caller asked for."""
+    if jax.default_backend() == "tpu":
+        return False
+    return True if requested is None else requested
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0) -> jax.Array:
@@ -54,7 +50,7 @@ def matmul(a, b, *, bm: int = 512, bn: int = 1024, bk: int = 512,
            interpret: Optional[bool] = None):
     from .matmul import matmul_pallas
 
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret(interpret)
     M, K = a.shape
     _, N = b.shape
     gm, gn, gk = min(bm, 128), min(bn, 128), min(bk, 128)
@@ -77,7 +73,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = Non
                     interpret: Optional[bool] = None):
     from .flash_attention import flash_attention_pallas
 
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret(interpret)
     B, H, Sq, hd = q.shape
     Skv = k.shape[2]
     bq_ = min(bq, max(Sq, 8))
@@ -100,7 +96,7 @@ def mamba_scan(dA, dBx, C, *, bd: int = 512, chunk: int = 64,
                interpret: Optional[bool] = None):
     from .mamba_scan import mamba_scan_pallas
 
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret(interpret)
     B, S, DI, N = dA.shape
     chunk_ = min(chunk, S)
     pad_s = (-S) % chunk_
@@ -119,7 +115,7 @@ def mamba_scan(dA, dBx, C, *, bd: int = 512, chunk: int = 64,
 def glm_fused(z, y, *, bm: int = 1024, interpret: Optional[bool] = None):
     from .glm_fused import glm_fused_pallas
 
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _interpret(interpret)
     n, d = z.shape
     bm_ = min(bm, n)
     while n % bm_:

@@ -33,6 +33,7 @@ import json
 import numpy as np
 
 from repro.core import ArrayContext, ClusterSpec
+from repro.launch.persistent_cache import enable_persistent_cache
 from repro.launch.workloads import (
     cpals_loop,
     dgemm_graph,
@@ -130,6 +131,7 @@ def main() -> None:
                     help="calibration profile JSON to apply to the cost "
                          "model (written instead when --calibrate is set)")
     args = ap.parse_args()
+    enable_persistent_cache()
 
     calibration = None
     if args.calibrate:

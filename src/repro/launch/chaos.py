@@ -34,6 +34,7 @@ import numpy as np
 from repro.core import ArrayContext, ChaosPlan, ClusterSpec, RetryPolicy
 from repro.core.elastic import elastic_relayout
 from repro.glm.newton import _single_block_binary
+from repro.launch.persistent_cache import enable_persistent_cache
 
 
 def _newton_iteration(ctx, X, y, beta, eye):
@@ -417,6 +418,7 @@ def main() -> None:
                          "zero budget violations and, with --controller, "
                          ">= 1 autonomous action")
     args = ap.parse_args()
+    enable_persistent_cache()
     calibration = None
     if args.calibrate:
         from repro.obs.calibrate import run_calibration

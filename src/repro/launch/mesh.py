@@ -9,19 +9,21 @@ inter-pod links, mirroring the paper's node/worker bandwidth hierarchy.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_axis: int = 1):
     """Degenerate mesh over whatever devices exist (tests / CPU runs)."""
     n = len(jax.devices())
     data = n // model_axis
-    return jax.make_mesh((data, model_axis), ("data", "model"))
+    return jax.make_mesh((data, model_axis), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_axis_sizes(mesh) -> dict:

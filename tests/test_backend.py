@@ -279,6 +279,17 @@ def test_no_host_transfers_between_ops():
     assert stats.d2h > d2h0
 
 
+def test_op_without_lowering_raises():
+    # no silent numpy round-trip through the host for an unknown op
+    from repro.backend.jax_backend import JaxBackend
+
+    be = JaxBackend("float32", cache=CompileCache())
+    x = be.from_host(np.ones((4, 4)), (0, 0))
+    with pytest.raises(NotImplementedError, match="no_such_op"):
+        be.execute("no_such_op", {}, [x], (0, 0))
+    assert be.stats.fallbacks == 0
+
+
 def test_blocks_stay_jax_arrays():
     import jax
 

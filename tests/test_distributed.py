@@ -14,6 +14,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_fake_devices(code: str, n: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, env=env, timeout=600)
@@ -183,7 +184,7 @@ class TestElasticRemesh:
         continue — loss trajectory stays continuous."""
         out = run_fake_devices(f"""
             import jax, jax.numpy as jnp, numpy as np
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
             from repro.checkpoint import restore, save
             from repro.configs import get_config
             from repro.sharding.plans import Plan, activation_rules, param_sharding_tree
@@ -195,7 +196,8 @@ class TestElasticRemesh:
             data = DataConfig(vocab=cfg.vocab, seq_len=16, global_batch=8, seed=1)
 
             def build(model_axis, plan_name):
-                mesh = jax.make_mesh((8 // model_axis, model_axis), ("data", "model"))
+                mesh = jax.make_mesh((8 // model_axis, model_axis), ("data", "model"),
+                                     axis_types=(AxisType.Auto,) * 2)
                 plan = Plan(plan_name, batch_axes=("data",), tp_axis="model",
                             fsdp_axis=("data",), remat="dots")
                 rules = activation_rules(plan, mesh, cfg)

@@ -13,7 +13,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_example(args, timeout=420):
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable] + args, capture_output=True, text=True,
                        env=env, timeout=timeout, cwd=REPO)
     assert r.returncode == 0, r.stderr[-2000:]

@@ -1,0 +1,74 @@
+"""Compiles of the block runtime's main path for a described TPU v5e chip.
+
+Nothing here runs on a chip: each test compiles one kernel or block op for
+the first chip of a described ``v5e:2x2`` topology, at the shapes the chip
+smoke test (``chip_smoke.py``) runs, and fails where the chip's compiler
+would refuse it.  The topology is described inside a fixture, never at
+import, so every test worker collects the same tests.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# one of the eight row blocks of an 11 000 000 x 28 HIGGS-shaped design matrix
+HIGGS_BLOCK = (1_375_000, 28)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler can be loaded here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent cache
+        # but cannot be read back without the chip: keep the cache out of it
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def jax_backend():
+    from repro.backend.jax_backend import JaxBackend
+
+    return JaxBackend("float32")
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
+            for s in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_pallas_matmul_compiles_to_mosaic(one_chip):
+    from repro.kernels.ops import matmul
+
+    compiled = _compile(lambda a, b: matmul(a, b, interpret=False), one_chip,
+                        (4096, 4096), (4096, 4096))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("op, meta, shapes", [
+    ("matmul", {"ta": True}, [HIGGS_BLOCK, HIGGS_BLOCK]),   # X^T (w X)
+    ("matmul", {}, [HIGGS_BLOCK, (28, 1)]),                 # X @ beta
+    ("sigmoid", {}, [(HIGGS_BLOCK[0], 1)]),                 # mu
+    ("solve", {}, [(28, 28), (28, 1)]),                     # Newton step
+], ids=["xtwx", "x_beta", "sigmoid", "solve"])
+def test_newton_block_op_compiles(one_chip, jax_backend, op, meta, shapes):
+    compiled = _compile(jax_backend._build(op, meta), one_chip, *shapes)
+    mem = compiled.memory_analysis()
+    # every operand and result of a block op fits one chip's 16 GB of HBM
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
