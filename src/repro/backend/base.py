@@ -38,29 +38,38 @@ class BackendStats:
     dispatches, and ``SchedStats``, which counts scheduling time)."""
 
     dispatches: int = 0     # execute() calls (one per block op)
+    dispatch_s: float = 0.0  # host time issuing cached compiled ops (nums:dispatch)
     jit_calls: int = 0      # compiled-callable invocations (jax/pallas)
     h2d: int = 0            # host -> device commits (from_host)
     d2h: int = 0            # device -> host gathers (to_host)
+    sync_s: float = 0.0     # host time blocked on the device (nums:sync)
     device_moves: int = 0   # device -> device operand moves
+    device_move_bytes: int = 0  # bytes of those operands (nums:move)
     fallbacks: int = 0      # ops run on the host: 0, a missing lowering raises
     replays: int = 0        # lineage-replay re-executions (fault recovery)
 
     def reset(self) -> None:
         self.dispatches = 0
+        self.dispatch_s = 0.0
         self.jit_calls = 0
         self.h2d = 0
         self.d2h = 0
+        self.sync_s = 0.0
         self.device_moves = 0
+        self.device_move_bytes = 0
         self.fallbacks = 0
         self.replays = 0
 
-    def as_dict(self) -> Dict[str, int]:
+    def as_dict(self) -> Dict[str, float]:
         return {
             "backend_dispatches": self.dispatches,
+            "backend_dispatch_s": self.dispatch_s,
             "backend_jit_calls": self.jit_calls,
             "backend_h2d": self.h2d,
             "backend_d2h": self.d2h,
+            "backend_sync_s": self.sync_s,
             "backend_device_moves": self.device_moves,
+            "backend_device_move_bytes": self.device_move_bytes,
             "backend_fallbacks": self.fallbacks,
             "backend_replays": self.replays,
         }

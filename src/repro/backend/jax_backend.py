@@ -25,12 +25,18 @@ the backend can be bit-comparable to the numpy reference — see
 """
 from __future__ import annotations
 
-from time import perf_counter
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core.graph_array import apply_chain
+from repro.core.trace import (
+    SPAN_COMPILE,
+    SPAN_DISPATCH,
+    SPAN_MOVE,
+    SPAN_SYNC,
+    Span,
+)
 
 from .base import BlockBackend
 from .compile_cache import GLOBAL_COMPILE_CACHE, CompileCache, structural_key
@@ -102,10 +108,12 @@ class JaxBackend(BlockBackend):
 
     def to_host(self, value) -> np.ndarray:
         self.stats.d2h += 1
-        return np.asarray(value)
+        with Span(SPAN_SYNC, self.stats, "sync_s"):
+            return np.asarray(value)
 
     def wait(self, value) -> None:
-        self._jax.block_until_ready(value)
+        with Span(SPAN_SYNC, self.stats, "sync_s"):
+            self._jax.block_until_ready(value)
 
     # -- execution ----------------------------------------------------------
     def execute(self, op: str, meta: Dict[str, Any], inputs: Sequence[Any],
@@ -127,7 +135,8 @@ class JaxBackend(BlockBackend):
             self.stats.jit_calls += 1
             if tr is not None:
                 tr.record("compile_hit", op, placement[0], placement[1])
-            return fn(*inputs)
+            with Span(SPAN_DISPATCH, self.stats, "dispatch_s"):
+                return fn(*inputs)
         builder = build(op, meta)
         if builder is None:
             # no silent host round-trip: it would hide the device on the
@@ -135,14 +144,14 @@ class JaxBackend(BlockBackend):
             raise NotImplementedError(
                 f"{self.name} backend has no lowering for block op {op!r}")
         jitted = self._jax.jit(builder)
-        t0 = perf_counter()
         self.stats.jit_calls += 1
-        out = jitted(*inputs)
-        self._jax.block_until_ready(out)  # charge compile+first-run to compile_s
-        self._cache.put(key, jitted, compile_seconds=perf_counter() - t0)
+        with Span(SPAN_COMPILE) as span:
+            out = jitted(*inputs)
+            self._jax.block_until_ready(out)  # compile_s is compile + first run
+        self._cache.put(key, jitted, compile_seconds=span.elapsed)
         if tr is not None:
             tr.record("compile_miss", op, placement[0], placement[1],
-                      args={"compile_s": perf_counter() - t0})
+                      args={"compile_s": span.elapsed})
         return out
 
     def _signature(self, inputs) -> Tuple[Tuple[Tuple[int, ...], str], ...]:
@@ -158,8 +167,10 @@ class JaxBackend(BlockBackend):
         out = []
         for x in inputs:
             if getattr(x, "devices", None) is not None and x.devices() != {dev}:
-                x = self._jax.device_put(x, dev)
+                with Span(SPAN_MOVE):
+                    x = self._jax.device_put(x, dev)
                 self.stats.device_moves += 1
+                self.stats.device_move_bytes += x.nbytes
             out.append(x)
         return out
 

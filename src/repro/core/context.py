@@ -15,7 +15,6 @@ from __future__ import annotations
 import os
 import random
 import zlib
-from time import perf_counter
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -34,6 +33,15 @@ from .plan import (
     structure_counts,
 )
 from .schedulers import SchedulerBase, make_scheduler
+from .trace import (
+    PYGC,
+    SPAN_COMPUTE,
+    SPAN_FINGERPRINT,
+    SPAN_REPLAY,
+    SPAN_SCHEDULE,
+    Span,
+    install_gc_spans,
+)
 
 
 class ArrayContext:
@@ -168,6 +176,7 @@ class ArrayContext:
         # — the key schema is golden-tested per feature set in test_obs
         from repro.obs.metrics import MetricsRegistry
 
+        install_gc_spans()  # process-wide: pygc_s / pygc_gen2 below
         self.metrics = MetricsRegistry()
         self._register_metrics()
 
@@ -205,6 +214,8 @@ class ArrayContext:
                 "drain_s": st.drain_s,
                 "reshards": st.reshards,
                 "reshard_moved": st.reshard_moved_elements,
+                "pygc_s": PYGC.seconds,
+                "pygc_gen2": PYGC.full,
             }
 
         def _comm():
@@ -304,6 +315,11 @@ class ArrayContext:
     def compute(self, ga: GraphArray) -> GraphArray:
         if ga.is_materialized():
             return ga
+        with Span(SPAN_COMPUTE):
+            self._schedule(ga)
+        return ga
+
+    def _schedule(self, ga: GraphArray) -> None:
         if self.fuse_enabled:
             from .fusion import fuse_graph
 
@@ -327,37 +343,35 @@ class ArrayContext:
         # re-schedule repeats the recorded plan's decisions exactly (see
         # plan.py).  With the cache off, only the count-based summary is
         # needed — the full token stream is skipped.
-        t0 = perf_counter()
-        if self.plan_cache is not None:
-            fp = fingerprint(roots, forced, self.state, self._config_sig)
-            rng_key = fp.rng_key
-        else:
-            fp = None
-            rng_key = structure_counts(roots)
-        stats.fingerprint_s += perf_counter() - t0
+        with Span(SPAN_FINGERPRINT, stats, "fingerprint_s"):
+            if self.plan_cache is not None:
+                fp = fingerprint(roots, forced, self.state, self._config_sig)
+                rng_key = fp.rng_key
+            else:
+                fp = None
+                rng_key = structure_counts(roots)
         rng = random.Random(rng_key ^ (self._seed * 2654435761))
         self.state.begin_schedule((rng_key >> 7) % self.cluster.workers_per_node)
         if fp is not None:
             cached = self.plan_cache.get(fp.key)
             if cached is not None:
-                t1 = perf_counter()
-                replay_plan(cached, fp.verts, self.state, self.executor, stats=stats)
-                stats.replay_s += perf_counter() - t1
+                with Span(SPAN_REPLAY, stats, "replay_s"):
+                    replay_plan(cached, fp.verts, self.state, self.executor,
+                                stats=stats)
                 stats.plan_hits += 1
                 if self.tracer is not None:
                     self.tracer.record(
                         "plan_hit", f"fp:{fp.rng_key & 0xFFFF:04x}",
                         args={"roots": len(roots)})
-                return ga
+                return
             recorder = PlanRecorder(fp.cid_of)
         else:
             recorder = None
         for root in roots:
             self._annotate_dest(root, forced[root.vid][0])
-        t1 = perf_counter()
-        self.scheduler.schedule(roots, forced, self.state, self.executor, rng,
-                                recorder=recorder, stats=stats)
-        stats.sched_cold_s += perf_counter() - t1
+        with Span(SPAN_SCHEDULE, stats, "sched_cold_s"):
+            self.scheduler.schedule(roots, forced, self.state, self.executor,
+                                    rng, recorder=recorder, stats=stats)
         if recorder is not None:
             self.plan_cache.put(fp.key, recorder.plan())
             stats.plan_misses += 1
@@ -365,7 +379,6 @@ class ArrayContext:
                 self.tracer.record(
                     "plan_miss", f"fp:{fp.rng_key & 0xFFFF:04x}",
                     args={"roots": len(roots)})
-        return ga
 
     @staticmethod
     def _annotate_dest(root, node: int) -> None:

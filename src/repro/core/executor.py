@@ -51,6 +51,7 @@ import numpy as np
 
 from .graph_array import GraphArray, infer_shape
 from .memory import MemoryManager
+from .trace import SPAN_DRAIN, Span
 
 _MODES = ("numpy", "sim", "jax", "pallas")
 
@@ -84,21 +85,17 @@ class PendingOp:
 class ExecStats:
     n_rfc: int = 0          # remote function calls dispatched (the γ term)
     n_creates: int = 0
-    elements_computed: int = 0
     n_queued: int = 0       # ops that went through the pipelined queues
     n_flushes: int = 0      # event-loop drains
     peak_queue: int = 0     # max total ops pending at once
-    dispatch_s: float = 0.0  # wall time inside run_op — the γ term in seconds
     drain_s: float = 0.0    # wall time inside flush() — pipelined queue drain
 
     def reset(self) -> None:
         self.n_rfc = 0
         self.n_creates = 0
-        self.elements_computed = 0
         self.n_queued = 0
         self.n_flushes = 0
         self.peak_queue = 0
-        self.dispatch_s = 0.0
         self.drain_s = 0.0
 
 
@@ -251,9 +248,7 @@ class Executor:
     ) -> None:
         """Dispatch one block op.  ``eta`` is the scheduler's simulated
         (start, finish) for the op (from ``ClusterState.transition``); in
-        pipelined mode it orders the event-loop drain.  Wall time spent here
-        accumulates in ``stats.dispatch_s`` (the per-op γ overhead, Fig. 8)."""
-        t0 = perf_counter()
+        pipelined mode it orders the event-loop drain."""
         self.stats.n_rfc += 1
         lineage_rec = OpRecord(
             out_id, op, dict(meta), tuple(in_ids), placement, times=eta
@@ -275,7 +270,6 @@ class Executor:
             self.memory.on_materialize(out_id, placement[0],
                                        int(np.prod(out_shape)) if out_shape
                                        else 1)
-            self.stats.dispatch_s += perf_counter() - t0
             return
         # refcount GC: each dispatched consumer pins its operands until it
         # retires (unpinned in _execute) — a pinned block is never evicted
@@ -294,10 +288,7 @@ class Executor:
             self._pending_ids.add(out_id)
             self.stats.n_queued += 1
             self.stats.peak_queue = max(self.stats.peak_queue, len(self._pending_ids))
-            self.stats.dispatch_s += perf_counter() - t0
             return
-        # sync mode: dispatch accounting stops before the block math itself
-        self.stats.dispatch_s += perf_counter() - t0
         if self.chaos is not None:
             head = PendingOp(out_id, op, dict(meta), tuple(in_ids), placement,
                              eta=eta[1] if eta else 0.0, seq=self._seq,
@@ -340,7 +331,6 @@ class Executor:
             wall_s = perf_counter() - w0
         else:
             out = self.backend.execute(op, meta, ins, placement)
-        self.stats.elements_computed += out_elements
         self.store[out_id] = out
         self.memory.on_materialize(out_id, placement[0], out_elements)
         self.memory.unpin(in_ids)
@@ -387,20 +377,21 @@ class Executor:
         is re-examined exactly when that dependency retires; each queue is
         always in exactly one of {on the heap, waiting, empty}.
 
-        Wall time spent draining accumulates in ``stats.drain_s`` — kept
-        separate from ``dispatch_s`` (enqueue-side ``run_op`` overhead) so
-        the scheduler-vs-dispatch overhead split in ``bench_overhead``
-        accounts pipelined queue time instead of under-reporting it."""
+        The outermost drain is the ``nums:drain`` span, its wall time in
+        ``stats.drain_s`` — kept apart from the schedulers' enqueue-side
+        ``dispatch_s`` so the scheduler-vs-dispatch overhead split in
+        ``bench_overhead`` accounts pipelined queue time instead of
+        under-reporting it."""
         if not self._pending_ids:
             return 0
-        t_drain = perf_counter()
         self._flush_depth += 1
         try:
-            return self._flush_inner()
+            if self._flush_depth > 1:  # timed by the drain it runs inside
+                return self._flush_inner()
+            with Span(SPAN_DRAIN, self.stats, "drain_s"):
+                return self._flush_inner()
         finally:
             self._flush_depth -= 1
-            if self._flush_depth == 0:
-                self.stats.drain_s += perf_counter() - t_drain
 
     def _flush_inner(self) -> int:
         executed = 0

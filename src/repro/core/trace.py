@@ -63,14 +63,176 @@ Summarize from the shell with::
 
 which prints the critical path and the makespan decomposition
 (compute / transfer / queue-stall / retry / eviction-stall per node).
+
+Host spans on the device's clock
+--------------------------------
+The flight recorder's clocks are simulated, and its ``wall`` runs from its
+own epoch: neither lines up with a device trace.  The block runtime's host
+work is therefore also marked with :class:`Span`, always on.  Each span
+emits a ``jax.profiler.TraceAnnotation`` under one of a fixed set of names
+and adds its wall time to a counter of its layer's stats object, which
+``ArrayContext.loads()`` reports:
+
+=====================  ====================================  ===========================
+span                   region                                ``loads()`` counter
+=====================  ====================================  ===========================
+``nums:compute``       ``ArrayContext.compute``              (parent of the next three)
+``nums:fingerprint``   structural fingerprint of a graph     ``fingerprint_s`` (in
+                                                             ``sched_overhead_s``)
+``nums:replay``        replay of a cached plan               ``replay_s`` (same)
+``nums:schedule``      cold LSHS placement                   ``sched_cold_s`` (same)
+``nums:drain``         outermost ``Executor.flush``          ``drain_s``
+``nums:dispatch``      one compiled block op issued          ``backend_dispatch_s``
+                       (``JaxBackend._dispatch``, hit)
+``nums:compile``       compile and first run of a block op   the compile cache's
+                       (``JaxBackend._dispatch``, miss)      ``compile_s``
+``nums:sync``          host blocked on the device            ``backend_sync_s``
+                       (``JaxBackend.wait``/``to_host``)
+``nums:move``          one cross-chip ``device_put``         ``backend_device_move_bytes``
+                       (``JaxBackend._colocate``)            (operand ``nbytes``)
+``nums:pygc``          one CPython cyclic collection         ``pygc_s``, ``pygc_gen2``
+                       (``gc.callbacks``, process-wide)      (full collections)
+``nums:newton.iter``   one ``NewtonSolver.fit`` iteration    none
+=====================  ====================================  ===========================
+
+They nest: ``fingerprint``, ``replay`` and ``schedule`` lie in
+``compute``; ``dispatch``, ``compile`` and ``move`` lie in ``drain`` (in
+``compute`` itself for a synchronous executor); ``pygc`` can fall inside any
+span.  Capture them with the JAX profiler around any program::
+
+    with jax.profiler.trace("prof"):
+        GLM(ctx).fit(X, y)
+
+and open the ``.xplane.pb`` under ``prof/plugins/profile/`` in
+TensorBoard's profile plugin, or in https://ui.perfetto.dev (pass
+``create_perfetto_trace=True`` for a ``perfetto_trace.json.gz``).  The spans
+sit in the host plane of the same file as the device's ops, on the same
+clock.  With no profiler session running a span costs one check beyond its
+two clock reads; where jax cannot be imported it is its timer only.
 """
 from __future__ import annotations
 
+import gc
 from collections import deque
 from time import perf_counter
 from typing import Any, Callable, Dict, Iterable, List, Optional
 
 DEFAULT_CAPACITY = 1 << 17  # 131072 events; smoke-scale runs use ~1e4
+
+# the fixed set of host span names (module docstring)
+SPAN_COMPUTE = "nums:compute"
+SPAN_FINGERPRINT = "nums:fingerprint"
+SPAN_REPLAY = "nums:replay"
+SPAN_SCHEDULE = "nums:schedule"
+SPAN_DRAIN = "nums:drain"
+SPAN_DISPATCH = "nums:dispatch"
+SPAN_COMPILE = "nums:compile"
+SPAN_SYNC = "nums:sync"
+SPAN_MOVE = "nums:move"
+SPAN_PYGC = "nums:pygc"
+SPAN_NEWTON_ITER = "nums:newton.iter"
+
+
+class _NoProfiler:
+    """Stands in for ``TraceAnnotation`` where jax cannot be imported."""
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+
+_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation, resolved on first use
+
+
+def _annotation_class() -> Any:
+    global _ANNOTATION
+    try:
+        from jax.profiler import TraceAnnotation as cls
+    except ImportError:
+        cls = _NoProfiler
+    _ANNOTATION = cls
+    return cls
+
+
+class Span:
+    """One host region: a profiler annotation named ``name`` while a profiler
+    session runs, and its wall seconds in ``elapsed`` on exit, added to
+    ``stats.<field>`` when a field is given.
+
+    ``with Span(SPAN_DRAIN, self.stats, "drain_s"): ...``
+    """
+
+    __slots__ = ("name", "stats", "field", "elapsed", "_t0", "_ann")
+
+    def __init__(self, name: str, stats: Any = None, field: Optional[str] = None):
+        self.name = name
+        self.stats = stats
+        self.field = field
+        self.elapsed = 0.0
+
+    # The annotation stamps its start when it is made and its end on exit,
+    # so the timer's reads sit right after each stamp: the span's duration
+    # in the trace and in its counter differ by two clock reads.
+    def __enter__(self) -> "Span":
+        cls = _ANNOTATION or _annotation_class()
+        if cls.is_enabled():
+            self._ann = ann = cls(self.name)
+            self._t0 = perf_counter()
+            ann.__enter__()
+        else:
+            self._ann = None
+            self._t0 = perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self.elapsed = perf_counter() - self._t0
+        if self.field is not None:
+            setattr(self.stats, self.field,
+                    getattr(self.stats, self.field) + self.elapsed)
+
+
+class GcSpans:
+    """CPython's cyclic collector as ``nums:pygc`` spans, through
+    ``gc.callbacks``: the wall seconds of every collection in ``seconds``,
+    full (generation 2) collections counted in ``full``.  One instance per
+    process (``PYGC``); a collection is annotated only once a :class:`Span`
+    has resolved the profiler (no import runs inside the collector)."""
+
+    def __init__(self) -> None:
+        self.seconds = 0.0
+        self.full = 0
+        self._clock = perf_counter  # still bound while modules are torn down
+        self._t0 = 0.0
+        self._ann = None
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            cls = _ANNOTATION
+            if cls is not None and cls.is_enabled():
+                self._ann = cls(SPAN_PYGC)
+                self._t0 = self._clock()
+                self._ann.__enter__()
+            else:
+                self._t0 = self._clock()
+            return
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self.seconds += self._clock() - self._t0
+        if info["generation"] == 2:
+            self.full += 1
+
+
+PYGC = GcSpans()
+
+
+def install_gc_spans() -> GcSpans:
+    """Hook :data:`PYGC` into ``gc.callbacks``; a second call adds nothing."""
+    if not any(cb is PYGC for cb in gc.callbacks):
+        gc.callbacks.append(PYGC)
+    return PYGC
 
 
 class TraceEvent:

@@ -17,6 +17,7 @@ import numpy as np
 from repro.core import ArrayContext, GraphArray
 from repro.core.grid import ArrayGrid
 from repro.core.graph_array import Vertex
+from repro.core.trace import SPAN_NEWTON_ITER, Span
 
 
 def _single_block_binary(ctx: ArrayContext, op: str, A: GraphArray, B: GraphArray) -> GraphArray:
@@ -55,22 +56,23 @@ class NewtonSolver:
             eye = ctx.from_numpy(self.reg * np.eye(d), grid=(1, 1))
         res = FitResult(beta=beta, iterations=0)
         for it in range(self.max_iter):
-            mu = model.mean(X, beta).compute()
-            g = (X.T @ (mu - y)).compute()
-            if self.reg > 0:
-                g = (g + self.reg * beta).compute()
-            w = model.hessian_weights(mu).compute()
-            C = (w * X).compute()
-            H = (X.T @ C).compute()
-            if eye is not None:
-                H = (H + eye).compute()
-            gnorm = float(np.sqrt((g * g).sum().to_numpy()))
-            res.grad_norms.append(gnorm)
-            res.iterations = it + 1
-            if gnorm <= self.tol:
-                res.converged = True
-                break
-            delta = _single_block_binary(ctx, "solve", H, g).compute()
-            beta = (beta - delta).compute()
-            res.beta = beta
+            with Span(SPAN_NEWTON_ITER):  # one span per iteration in a trace
+                mu = model.mean(X, beta).compute()
+                g = (X.T @ (mu - y)).compute()
+                if self.reg > 0:
+                    g = (g + self.reg * beta).compute()
+                w = model.hessian_weights(mu).compute()
+                C = (w * X).compute()
+                H = (X.T @ C).compute()
+                if eye is not None:
+                    H = (H + eye).compute()
+                gnorm = float(np.sqrt((g * g).sum().to_numpy()))
+                res.grad_norms.append(gnorm)
+                res.iterations = it + 1
+                if gnorm <= self.tol:
+                    res.converged = True
+                    break
+                delta = _single_block_binary(ctx, "solve", H, g).compute()
+                beta = (beta - delta).compute()
+                res.beta = beta
         return res

@@ -382,9 +382,10 @@ def run_calibration(*, backend: str = "jax", nodes: int = 4, workers: int = 2,
     finally:
         ctx.executor.profile_sync = False
     _probe_transfers(ctx.executor.backend, rec)
-    st = ctx.executor.stats
+    # γ: transition + run_op seconds (the loads() key ``dispatch_s``) per RFC
     rec.record("gamma_probe", "gamma", args={
-        "dispatch_s": st.dispatch_s, "n_rfc": st.n_rfc})
+        "dispatch_s": ctx.sched_stats.dispatch_s,
+        "n_rfc": ctx.executor.stats.n_rfc})
 
     try:
         from repro.launch.mesh import device_class

@@ -58,11 +58,12 @@ SUMMARY_KEYS = [
 RUNTIME_KEYS = [
     "n_rfc", "transfers", "makespan", "pending_ops", "plan_hits",
     "plan_misses", "sched_overhead_s", "dispatch_s", "drain_s", "reshards",
-    "reshard_moved",
+    "reshard_moved", "pygc_s", "pygc_gen2",
 ]
 BACKEND_KEYS = [
-    "backend_dispatches", "backend_jit_calls", "backend_h2d", "backend_d2h",
-    "backend_device_moves", "backend_fallbacks", "backend_replays",
+    "backend_dispatches", "backend_dispatch_s", "backend_jit_calls",
+    "backend_h2d", "backend_d2h", "backend_sync_s", "backend_device_moves",
+    "backend_device_move_bytes", "backend_fallbacks", "backend_replays",
 ]
 MEM_KEYS = [
     "mem_capacity", "mem_high_watermark", "mem_low_watermark",
