@@ -19,7 +19,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Optional
 
-from .base import BackendStats, BlockBackend
+from .base import BackendStats, BlockBackend, Program
 from .compile_cache import GLOBAL_COMPILE_CACHE, CompileCache, structural_key
 from .numpy_backend import NumpyBackend
 
@@ -84,6 +84,7 @@ __all__ = [
     "GLOBAL_COMPILE_CACHE",
     "NATURAL_DTYPE",
     "NumpyBackend",
+    "Program",
     "available_backends",
     "make_backend",
     "register_backend",

@@ -15,6 +15,9 @@ Contract:
   call them between ops, which the host-transfer regression test asserts.
 * ``execute(op, meta, inputs, placement)`` runs one block-level op on
   backend-resident inputs and returns a backend-resident output.
+* ``run_program(program, inputs, placement)`` runs a ``Program`` — a
+  segment of block ops — as one compiled call (compiled backends only;
+  the pipelined executor lowers cached plans through it).
 * ``compile_cache`` is the backend's structural compile cache (``None`` for
   interpreters with nothing to compile).
 
@@ -29,7 +32,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .compile_cache import CompileCache
+from .compile_cache import CompileCache, program_key
 
 
 @dataclass
@@ -37,7 +40,7 @@ class BackendStats:
     """Execution-substrate counters (complement ``ExecStats``, which counts
     dispatches, and ``SchedStats``, which counts scheduling time)."""
 
-    dispatches: int = 0     # execute() calls (one per block op)
+    dispatches: int = 0     # host calls of compiled executables (an op or a program)
     dispatch_s: float = 0.0  # host time issuing cached compiled ops (nums:dispatch)
     jit_calls: int = 0      # compiled-callable invocations (jax/pallas)
     h2d: int = 0            # host -> device commits (from_host)
@@ -47,6 +50,8 @@ class BackendStats:
     device_move_bytes: int = 0  # bytes of those operands (nums:move)
     fallbacks: int = 0      # ops run on the host: 0, a missing lowering raises
     replays: int = 0        # lineage-replay re-executions (fault recovery)
+    programs: int = 0       # run_program() calls: one lowered segment each
+    lowered_ops: int = 0    # block ops run inside those programs
 
     def reset(self) -> None:
         self.dispatches = 0
@@ -59,6 +64,8 @@ class BackendStats:
         self.device_move_bytes = 0
         self.fallbacks = 0
         self.replays = 0
+        self.programs = 0
+        self.lowered_ops = 0
 
     def as_dict(self) -> Dict[str, float]:
         return {
@@ -72,7 +79,27 @@ class BackendStats:
             "backend_device_move_bytes": self.device_move_bytes,
             "backend_fallbacks": self.fallbacks,
             "backend_replays": self.replays,
+            "backend_programs": self.programs,
+            "backend_lowered_ops": self.lowered_ops,
         }
+
+
+class Program:
+    """A segment of block ops that runs as one compiled call.
+
+    ``ops`` is a tuple of ``(op, meta, args)`` in a topological order; an
+    arg ``j >= 0`` is the result of the j-th op, ``~i`` the i-th input.
+    ``outputs`` lists the ops whose results the program returns, in order.
+    ``key`` is structural (op kinds, canonical metadata, wiring, outputs),
+    so structurally identical segments share one compilation."""
+
+    __slots__ = ("ops", "outputs", "key")
+
+    def __init__(self, ops: Tuple[Tuple[str, Dict[str, Any], Tuple[int, ...]], ...],
+                 outputs: Tuple[int, ...]):
+        self.ops = ops
+        self.outputs = outputs
+        self.key = program_key(ops, outputs)
 
 
 class BlockBackend:
@@ -97,6 +124,12 @@ class BlockBackend:
     # -- execution ----------------------------------------------------------
     def execute(self, op: str, meta: Dict[str, Any], inputs: Sequence[Any],
                 placement: Tuple[int, int]):
+        raise NotImplementedError
+
+    def run_program(self, program: Program, inputs: Sequence[Any],
+                    placement: Tuple[int, int]) -> Tuple[Any, ...]:
+        """Run ``program`` on backend-resident ``inputs`` at ``placement``;
+        return the results of ``program.outputs``."""
         raise NotImplementedError
 
     def wait(self, value) -> None:

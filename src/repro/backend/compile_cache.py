@@ -58,6 +58,17 @@ def structural_key(salt: str, op: str, meta: Dict[str, Any],
     )
 
 
+def program_key(ops, outputs: Tuple[int, ...]) -> tuple:
+    """Structural key of a ``Program`` (less its input signature): each op's
+    kind, canonical metadata and operand wiring, and the outputs."""
+    return (
+        _intern["program"],
+        tuple((_intern[op], _memo_meta_token(meta) if meta else (), args)
+              for op, meta, args in ops),
+        outputs,
+    )
+
+
 class CompileCache:
     """LRU map structural-key -> compiled callable, with compile accounting.
 

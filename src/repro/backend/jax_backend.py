@@ -38,7 +38,7 @@ from repro.core.trace import (
     Span,
 )
 
-from .base import BlockBackend
+from .base import BlockBackend, Program
 from .compile_cache import GLOBAL_COMPILE_CACHE, CompileCache, structural_key
 
 
@@ -116,43 +116,91 @@ class JaxBackend(BlockBackend):
             self._jax.block_until_ready(value)
 
     # -- execution ----------------------------------------------------------
+    @property
+    def device_count(self) -> int:
+        """Devices that placements map onto (node ``i`` -> device
+        ``i % device_count``, ``device_of``)."""
+        return len(self._devices)
+
     def execute(self, op: str, meta: Dict[str, Any], inputs: Sequence[Any],
                 placement: Tuple[int, int]):
-        return self._dispatch(self._salt, op, meta, inputs, placement,
-                              self._build)
-
-    def _dispatch(self, salt: str, op: str, meta: Dict[str, Any],
-                  inputs: Sequence[Any], placement: Tuple[int, int],
-                  build: Callable[[str, Dict[str, Any]], Optional[Callable]]):
-        """The one compile-cached dispatch protocol (shared with subclasses
-        that contribute their own lowerings under a different ``salt``)."""
         self.stats.dispatches += 1
         inputs = self._colocate(inputs, placement)
+        salt, _build = self._route(op, inputs)
         key = structural_key(salt, op, meta, self._signature(inputs))
+        return self._call(key, inputs, placement, op,
+                          lambda: self._lowering(op, meta, inputs))
+
+    def run_program(self, program: Program, inputs: Sequence[Any],
+                    placement: Tuple[int, int]) -> Tuple[Any, ...]:
+        """Run a segment of block ops as one compiled call.
+
+        The program traces each op with the lowering ``execute`` gives it,
+        and passes each op's result through ``lax.optimization_barrier``,
+        so XLA neither fuses nor reassociates across block ops: the device
+        does the per-op path's work op for op.  Each input is moved to the
+        placement's device once, however many ops read it."""
+        self.stats.dispatches += 1
+        self.stats.programs += 1
+        self.stats.lowered_ops += len(program.ops)
+        inputs = self._colocate(inputs, placement)
+        key = (self.name, program.key, self._signature(inputs))
+        return self._call(key, inputs, placement, "program",
+                          lambda: self._program_fn(program))
+
+    def _call(self, key: tuple, inputs: Sequence[Any],
+              placement: Tuple[int, int], label: str,
+              make: Callable[[], Callable]):
+        """The one compile-cached call protocol: call the executable cached
+        under ``key``, or jit ``make()`` and compile it on a miss."""
         fn = self._cache.get(key)
         tr = self.tracer
         if fn is not None:
             self.stats.jit_calls += 1
             if tr is not None:
-                tr.record("compile_hit", op, placement[0], placement[1])
+                tr.record("compile_hit", label, placement[0], placement[1])
             with Span(SPAN_DISPATCH, self.stats, "dispatch_s"):
                 return fn(*inputs)
-        builder = build(op, meta)
-        if builder is None:
-            # no silent host round-trip: it would hide the device on the
-            # chip path behind numpy
-            raise NotImplementedError(
-                f"{self.name} backend has no lowering for block op {op!r}")
-        jitted = self._jax.jit(builder)
+        jitted = self._jax.jit(make())
         self.stats.jit_calls += 1
         with Span(SPAN_COMPILE) as span:
             out = jitted(*inputs)
             self._jax.block_until_ready(out)  # compile_s is compile + first run
         self._cache.put(key, jitted, compile_seconds=span.elapsed)
         if tr is not None:
-            tr.record("compile_miss", op, placement[0], placement[1],
+            tr.record("compile_miss", label, placement[0], placement[1],
                       args={"compile_s": span.elapsed})
         return out
+
+    def _route(self, op: str, inputs: Sequence[Any]
+               ) -> Tuple[str, Callable[[str, Dict[str, Any]], Optional[Callable]]]:
+        """(compile-cache salt, builder) of the lowering ``op`` takes on
+        ``inputs`` (arrays or tracers); subclasses route some ops to their
+        own kernels under their own salt."""
+        return self._salt, self._build
+
+    def _lowering(self, op: str, meta: Dict[str, Any],
+                  inputs: Sequence[Any]) -> Callable:
+        fn = self._route(op, inputs)[1](op, meta)
+        if fn is None:
+            # no silent host round-trip: it would hide the device on the
+            # chip path behind numpy
+            raise NotImplementedError(
+                f"{self.name} backend has no lowering for block op {op!r}")
+        return fn
+
+    def _program_fn(self, program: Program) -> Callable:
+        barrier = self._jax.lax.optimization_barrier
+        ops, outputs = program.ops, program.outputs
+
+        def run(*xs):
+            vals = []
+            for op, meta, args in ops:
+                ins = [vals[j] if j >= 0 else xs[~j] for j in args]
+                vals.append(barrier(self._lowering(op, meta, ins)(*ins)))
+            return tuple(vals[k] for k in outputs)
+
+        return run
 
     def _signature(self, inputs) -> Tuple[Tuple[Tuple[int, ...], str], ...]:
         return tuple((tuple(x.shape), str(x.dtype)) for x in inputs)

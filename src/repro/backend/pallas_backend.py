@@ -15,7 +15,7 @@ ops share the jax backend's entries.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Sequence, Tuple
+from typing import Any, Dict, Sequence
 
 from .jax_backend import JaxBackend
 
@@ -23,12 +23,10 @@ from .jax_backend import JaxBackend
 class PallasBackend(JaxBackend):
     name = "pallas"
 
-    def execute(self, op: str, meta: Dict[str, Any], inputs: Sequence[Any],
-                placement: Tuple[int, int]):
-        if op != "matmul" or any(x.ndim != 2 for x in inputs):
-            return super().execute(op, meta, inputs, placement)
-        return self._dispatch("pallas", op, meta, inputs, placement,
-                              self._build_pallas_matmul)
+    def _route(self, op: str, inputs: Sequence[Any]):
+        if op == "matmul" and all(x.ndim == 2 for x in inputs):
+            return "pallas", self._build_pallas_matmul
+        return super()._route(op, inputs)
 
     def _build_pallas_matmul(self, op: str, meta: Dict[str, Any]):
         jnp = self._jnp

@@ -352,12 +352,19 @@ class ArrayContext:
                 rng_key = structure_counts(roots)
         rng = random.Random(rng_key ^ (self._seed * 2654435761))
         self.state.begin_schedule((rng_key >> 7) % self.cluster.workers_per_node)
+        ex = self.executor
         if fp is not None:
             cached = self.plan_cache.get(fp.key)
             if cached is not None:
-                with Span(SPAN_REPLAY, stats, "replay_s"):
-                    replay_plan(cached, fp.verts, self.state, self.executor,
-                                stats=stats)
+                # the replayed ops are tagged with their plan's segments, so
+                # a pipelined compiled executor runs them as programs
+                ex.begin_plan()
+                try:
+                    with Span(SPAN_REPLAY, stats, "replay_s"):
+                        replay_plan(cached, fp.verts, self.state, ex,
+                                    stats=stats)
+                finally:
+                    ex.end_plan(cached)
                 stats.plan_hits += 1
                 if self.tracer is not None:
                     self.tracer.record(
@@ -369,11 +376,21 @@ class ArrayContext:
             recorder = None
         for root in roots:
             self._annotate_dest(root, forced[root.vid][0])
-        with Span(SPAN_SCHEDULE, stats, "sched_cold_s"):
-            self.scheduler.schedule(roots, forced, self.state, self.executor,
-                                    rng, recorder=recorder, stats=stats)
         if recorder is not None:
-            self.plan_cache.put(fp.key, recorder.plan())
+            ex.begin_plan()
+        try:
+            with Span(SPAN_SCHEDULE, stats, "sched_cold_s"):
+                self.scheduler.schedule(roots, forced, self.state, ex,
+                                        rng, recorder=recorder, stats=stats)
+        except BaseException:
+            ex.end_plan(None)
+            raise
+        if recorder is not None:
+            plan = recorder.plan()
+            self.plan_cache.put(fp.key, plan)
+            # segments are computed as the plan is stored, so its programs
+            # compile on first sight
+            ex.end_plan(plan)
             stats.plan_misses += 1
             if self.tracer is not None:
                 self.tracer.record(

@@ -31,6 +31,19 @@ Two dispatch modes share one interface:
   blocked heads register a waiter on their first unmet dependency, so each
   retirement costs O(log Q) instead of rescanning every queue.
 
+Lowered plans: ops that a cached plan enqueued (``begin_plan``/``end_plan``
+tag them with their place in the plan's device segments, ``core/plan.py``)
+run on a compiled backend as one program per segment instead of one call
+per op.  The drain still walks every op in the same order with the same
+memory accounting and lineage; a lowered op stores a placeholder, and its
+segment's program runs once all its ops are walked and the segments feeding
+it have run (``JaxBackend.run_program``).  Only the blocks read outside the
+segment, or held by a handle when the drain begins, come back from the
+program; the rest never get a buffer of their own.  The drain runs op by op
+where the executor cannot see that this is safe: a chaos engine, a flight
+recorder, a drain hook, ``profile_sync``, a memory budget, or an op in the
+drain that no plan tagged.
+
 The executor also implements task-lineage replay for fault tolerance
 (``fail_node``/``recover``): every op's recipe is recorded so lost blocks can
 be re-executed idempotently — the GraphArray analogue of checkpoint/restart.
@@ -79,6 +92,48 @@ class PendingOp:
     seq: int    # dispatch order (deterministic tie-break)
     faults: int = 0          # chaos: seeded failed attempts to retry through
     spec_checked: bool = False  # chaos: speculation evaluated once per op
+    tag: Optional[Tuple["_PlanRun", int]] = None  # (plan run, op position)
+
+
+class _PlanRun:
+    """The ops one ``compute`` enqueued from one plan: its segments, and the
+    segment builders of the drain that runs them."""
+
+    __slots__ = ("segs", "builds")
+
+    def __init__(self, segs):
+        self.segs = segs
+        self.builds: List[Optional[_SegmentBuild]] = [None] * len(segs.segments)
+
+
+class _SegmentBuild:
+    """One segment of one plan run, gathered during a drain's walk."""
+
+    __slots__ = ("seg", "placement", "ops", "inputs", "slots", "vids", "left",
+                 "done", "waiters")
+
+    def __init__(self, seg, placement: Tuple[int, int]):
+        n = len(seg.positions)
+        self.seg = seg
+        self.placement = placement
+        self.ops: List[Any] = [None] * n        # (op, meta) per segment op
+        self.inputs: List[Any] = [None] * seg.n_inputs
+        self.slots: List[Any] = [None] * n
+        self.vids: List[int] = [0] * n
+        self.left = n                           # ops not walked yet
+        self.done = False
+        self.waiters: List[_SegmentBuild] = []  # segments that read this one
+
+
+class _Slot:
+    """The store's value of a lowered op until its segment's program has
+    run; then ``value`` holds the result (outputs only)."""
+
+    __slots__ = ("build", "value")
+
+    def __init__(self, build: _SegmentBuild):
+        self.build = build
+        self.value = None
 
 
 @dataclass
@@ -145,6 +200,11 @@ class Executor:
         # at one is-None test per retirement.
         self.profile_sync = False
         self.drain_hook = None
+        # plan lowering: ops enqueued between begin_plan and end_plan, and,
+        # during a lowered drain, the blocks its programs return
+        self._plan_ops: Optional[List[PendingOp]] = None
+        self._keep: Optional[set] = None
+        self._builds: List[_SegmentBuild] = []
         if mode == "sim":
             self.backend = None
             self.dtype = dtype or "float64"
@@ -285,6 +345,8 @@ class Executor:
             )
             self._seq += 1
             self.queues.setdefault(placement, deque()).append(pending)
+            if self._plan_ops is not None:
+                self._plan_ops.append(pending)
             self._pending_ids.add(out_id)
             self.stats.n_queued += 1
             self.stats.peak_queue = max(self.stats.peak_queue, len(self._pending_ids))
@@ -298,6 +360,118 @@ class Executor:
             return
         self._execute(out_id, op, meta, in_ids, placement)
 
+    # -- plan lowering ---------------------------------------------------------
+    def begin_plan(self) -> None:
+        """Collect the ops the next plan replay or cold schedule enqueues
+        (pipelined jax/pallas executors only)."""
+        if self.pipeline and self.mode in ("jax", "pallas"):
+            self._plan_ops = []
+
+    def end_plan(self, plan) -> None:
+        """Tag the ops enqueued since ``begin_plan`` with their place in
+        ``plan``'s device segments; ``None`` (or ops that do not match the
+        plan) leaves them untagged, to run op by op."""
+        ops, self._plan_ops = self._plan_ops, None
+        if not ops or plan is None:
+            return
+        segs = plan.segments(self.backend.device_count)
+        if len(ops) != len(segs.where):
+            return
+        run = _PlanRun(segs)
+        for p, pending in enumerate(ops):
+            pending.tag = (run, p)
+
+    def _lowered_outputs(self) -> Optional[set]:
+        """The blocks this drain's programs must return, or ``None`` where
+        the drain runs op by op.  A block is returned when an op of another
+        segment (or of another plan run) reads it, when a handle holds it as
+        the drain begins, when nothing in the drain reads it, or when there
+        is no refcount GC to free it.  Every other block is read only inside
+        its segment and is freed by the walk; it gets no buffer."""
+        if (self.tracer is not None or self.drain_hook is not None
+                or self.profile_sync or self.memory.capacity is not None):
+            return None
+        owner: Dict[int, Tuple[_PlanRun, int]] = {}
+        for q in self.queues.values():
+            for op in q:
+                if op.tag is None:
+                    return None
+                run, p = op.tag
+                owner[op.out_id] = (run, run.segs.where[p][0])
+        keep = set()
+        read = set()
+        resolve = self.resolve
+        for q in self.queues.values():
+            for op in q:
+                mine = owner[op.out_id]
+                for i in op.in_ids:
+                    r = resolve(i)
+                    src = owner.get(r)
+                    if src is not None:
+                        read.add(r)
+                        if src != mine:
+                            keep.add(r)
+        mm = self.memory
+        handles = mm.handles
+        for vid in owner:
+            if vid not in read or not mm.enabled or handles.get(vid, 0) > 0:
+                keep.add(vid)
+        return keep
+
+    def _lower(self, tag: Tuple[_PlanRun, int], op: str, meta: Dict[str, Any],
+               ins: List[Any], placement: Tuple[int, int], out_id: int) -> _Slot:
+        """Walk one lowered op: add it to its segment's builder and return
+        the placeholder the store holds until the program runs."""
+        run, p = tag
+        s, k = run.segs.where[p]
+        b = run.builds[s]
+        if b is None:
+            b = run.builds[s] = _SegmentBuild(run.segs.segments[s], placement)
+            self._builds.append(b)
+        b.ops[k] = (op, meta)
+        for j, a in enumerate(b.seg.args[k]):
+            if a < 0:
+                b.inputs[~a] = ins[j]
+        slot = b.slots[k] = _Slot(b)
+        b.vids[k] = out_id
+        b.left -= 1
+        return slot
+
+    def _run_segments(self, b: _SegmentBuild) -> None:
+        """Run ``b``'s program once every segment feeding it has run, then
+        each segment that waited on it."""
+        work = [b]
+        while work:
+            b = work.pop()
+            unrun = next((x for x in b.inputs
+                          if type(x) is _Slot and x.value is None), None)
+            if unrun is not None:
+                unrun.build.waiters.append(b)
+                continue
+            seg = b.seg
+            keep = self._keep
+            outputs = tuple(k for k, vid in enumerate(b.vids) if vid in keep)
+            prog = seg.programs.get(outputs)
+            if prog is None:
+                from repro.backend.base import Program
+
+                prog = seg.programs[outputs] = Program(
+                    tuple((op, meta, args)
+                          for (op, meta), args in zip(b.ops, seg.args)),
+                    outputs)
+            ins = [x.value if type(x) is _Slot else x for x in b.inputs]
+            b.inputs = None  # the program holds its operands while it runs
+            vals = self.backend.run_program(prog, ins, b.placement)
+            store = self.store
+            for k, val in zip(outputs, vals):
+                slot = b.slots[k]
+                slot.value = val
+                vid = b.vids[k]
+                if store.get(vid) is slot:  # not freed during the walk
+                    store[vid] = val
+            b.done = True
+            work.extend(b.waiters)
+
     def _execute(
         self,
         out_id: int,
@@ -305,6 +479,7 @@ class Executor:
         meta: Dict[str, Any],
         in_ids: Sequence[int],
         placement: Tuple[int, int],
+        tag: Optional[Tuple[_PlanRun, int]] = None,
     ) -> float:
         # memory gate first: over the high watermark the drain stalls here
         # (backpressure) while victims spill/drop, before the op materializes
@@ -320,7 +495,9 @@ class Executor:
         # operands flow to the backend in their resident representation
         # (numpy arrays / jax device arrays) — no host round-trip here
         ins = [self.get(i) for i in in_ids]
-        if tr is not None:
+        if tag is not None:
+            out = self._lower(tag, op, meta, ins, placement, out_id)
+        elif tr is not None:
             # measured wall time per op: the calibration/drift signal.
             # profile_sync blocks async backends so the window covers the
             # kernel, not just its dispatch.
@@ -334,6 +511,8 @@ class Executor:
         self.store[out_id] = out
         self.memory.on_materialize(out_id, placement[0], out_elements)
         self.memory.unpin(in_ids)
+        if tag is not None and out.build.left == 0:
+            self._run_segments(out.build)
         if tr is not None:
             # ``work`` mirrors the clock model's elements-touched measure
             # (output + every input) so retire events pair one-to-one with
@@ -394,9 +573,34 @@ class Executor:
             self._flush_depth -= 1
 
     def _flush_inner(self) -> int:
-        executed = 0
         if self.chaos is not None:
             return self._flush_chaos()
+        if self._keep is not None or self.mode not in ("jax", "pallas"):
+            return self._drain()  # op by op, or inside a lowered drain
+        self._keep = self._lowered_outputs()
+        if self._keep is None:
+            return self._drain()
+        try:
+            executed = self._drain()
+            if any(not b.done for b in self._builds):  # pragma: no cover
+                raise RuntimeError("lowered drain ended with segments unrun")
+        finally:
+            # every program has run and the store holds each kept result:
+            # drop the placeholders' references, so a block lives exactly as
+            # long as on the per-op path (slot <-> build is a cycle that
+            # would otherwise hold the results until a cyclic collection)
+            for b in self._builds:
+                for slot in b.slots:
+                    if slot is not None:
+                        slot.value = slot.build = None
+                b.slots = b.inputs = b.waiters = None
+            self._keep = None
+            self._builds = []
+        return executed
+
+    def _drain(self) -> int:
+        executed = 0
+        lowered = self._keep is not None
         ready: List[Tuple[float, int, Tuple[int, int]]] = []
         waiting: Dict[int, List[Tuple[int, int]]] = {}
         pending = self._pending_ids
@@ -425,7 +629,8 @@ class Executor:
             head = self.queues[qkey].popleft()
             # retire before executing: _execute->get must not re-enter flush
             pending.discard(head.out_id)
-            self._execute(head.out_id, head.op, head.meta, head.in_ids, head.placement)
+            self._execute(head.out_id, head.op, head.meta, head.in_ids,
+                          head.placement, head.tag if lowered else None)
             if self.retire_log is not None:
                 self.retire_log.append(head.out_id)
             if self.drain_hook is not None:

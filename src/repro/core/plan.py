@@ -269,10 +269,105 @@ class PlacementPlan:
     n_struct: int                  # canonical ids [0, n_struct) are graph vertices
     n_total: int                   # including scheduler-created temporaries
     steps: List[tuple] = field(default_factory=list)
+    # device count -> the plan's segments on that many devices (``segments``)
+    _segments: Dict[int, "PlanSegments"] = field(
+        default_factory=dict, repr=False, compare=False)
 
     @property
     def n_ops(self) -> int:
         return sum(1 for s in self.steps if s[0] != _ALIAS)
+
+    def segments(self, n_devices: int) -> "PlanSegments":
+        """The plan's device segments on ``n_devices`` devices, computed on
+        first use and kept, so a replay pays nothing for them."""
+        segs = self._segments.get(n_devices)
+        if segs is None:
+            segs = self._segments[n_devices] = plan_segments(self, n_devices)
+        return segs
+
+
+@dataclass
+class Segment:
+    """The ops of one plan on one device at one stage: what the executor
+    runs as one compiled program (``JaxBackend.run_program``).
+
+    ``args[k]`` names the operands of the segment's k-th op: ``j >= 0`` is
+    the result of its j-th op, ``~i`` its i-th input.  The inputs are the
+    blocks the segment reads from outside itself, each once.  ``programs``
+    holds the backend program built for each set of outputs, on first
+    dispatch."""
+
+    positions: List[int] = field(default_factory=list)  # plan op positions
+    args: List[Tuple[int, ...]] = field(default_factory=list)
+    n_inputs: int = 0
+    programs: Dict[tuple, object] = field(default_factory=dict, repr=False)
+
+
+@dataclass
+class PlanSegments:
+    """``where[p]`` is (segment, index in it) of the plan's p-th op (steps
+    in dispatch order, aliases skipped)."""
+
+    where: List[Tuple[int, int]]
+    segments: List[Segment]
+
+
+def plan_segments(plan: PlacementPlan, n_devices: int) -> PlanSegments:
+    """Group a plan's ops into device segments, from the plan alone.
+
+    An op runs on device ``node % n_devices`` (``JaxBackend.device_of``).
+    Its stage is the highest stage among its in-plan producers, plus one
+    where a producer sits on another device.  A segment is the ops of one
+    (device, stage).  Edges between segments only go to a higher stage or
+    stay on a device at an equal or higher stage, so segments form a DAG;
+    on one device a plan is one segment."""
+    pos_of: Dict[int, int] = {}  # cid -> producing op position, aliases followed
+    root: Dict[int, int] = {}    # alias cid -> the cid whose block it names
+    dev: List[int] = []
+    stage: List[int] = []
+    where: List[Tuple[int, int]] = []
+    seg_of: Dict[Tuple[int, int], int] = {}
+    segments: List[Segment] = []
+    inputs: List[Dict[int, int]] = []  # per segment: root cid -> input index
+    for step in plan.steps:
+        tag = step[0]
+        if tag == _ALIAS:
+            _tag, cid, src_cid, _pl, _elements = step
+            root[cid] = root.get(src_cid, src_cid)
+            if src_cid in pos_of:
+                pos_of[cid] = pos_of[src_cid]
+            continue
+        in_cids, pl = (step[2], step[3]) if tag == _OP else (step[3], step[4])
+        d = pl[0] % n_devices
+        st = 0
+        for c in in_cids:
+            q = pos_of.get(c)
+            if q is not None:
+                st = max(st, stage[q] + (dev[q] != d))
+        s = seg_of.get((d, st))
+        if s is None:
+            s = seg_of[(d, st)] = len(segments)
+            segments.append(Segment())
+            inputs.append({})
+        seg = segments[s]
+        args = []
+        for c in in_cids:
+            q = pos_of.get(c)
+            if q is not None and where[q][0] == s:
+                args.append(where[q][1])
+            else:
+                ins = inputs[s]
+                args.append(~ins.setdefault(root.get(c, c), len(ins)))
+        p = len(where)
+        where.append((s, len(seg.positions)))
+        seg.positions.append(p)
+        seg.args.append(tuple(args))
+        pos_of[step[1]] = p
+        dev.append(d)
+        stage.append(st)
+    for seg, ins in zip(segments, inputs):
+        seg.n_inputs = len(ins)
+    return PlanSegments(where, segments)
 
 
 class PlanRecorder:

@@ -82,10 +82,12 @@ span                   region                                ``loads()`` counter
 ``nums:replay``        replay of a cached plan               ``replay_s`` (same)
 ``nums:schedule``      cold LSHS placement                   ``sched_cold_s`` (same)
 ``nums:drain``         outermost ``Executor.flush``          ``drain_s``
-``nums:dispatch``      one compiled block op issued          ``backend_dispatch_s``
-                       (``JaxBackend._dispatch``, hit)
+``nums:dispatch``      one compiled block op or lowered      ``backend_dispatch_s``
+                       program issued (``JaxBackend._call``,
+                       hit)
 ``nums:compile``       compile and first run of a block op   the compile cache's
-                       (``JaxBackend._dispatch``, miss)      ``compile_s``
+                       or program (``JaxBackend._call``,     ``compile_s``
+                       miss)
 ``nums:sync``          host blocked on the device            ``backend_sync_s``
                        (``JaxBackend.wait``/``to_host``)
 ``nums:move``          one cross-chip ``device_put``         ``backend_device_move_bytes``

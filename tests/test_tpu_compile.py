@@ -72,3 +72,42 @@ def test_newton_block_op_compiles(one_chip, jax_backend, op, meta, shapes):
     # every operand and result of a block op fits one chip's 16 GB of HBM
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
+
+
+def test_lowered_newton_programs_compile(one_chip, jax_backend):
+    """Every segment program of a Newton fit on eight row blocks (the plans
+    the one-chip HIGGS cell replays) compiles at HIGGS block shapes, and the
+    largest fits one chip beside nothing else."""
+    import numpy as np
+
+    from repro.core import ArrayContext, ClusterSpec
+    from repro.glm import GLM
+
+    rows = 1_000  # rows of a block in the fit that yields the programs
+    ctx = ArrayContext(cluster=ClusterSpec(1, 8), node_grid=(1, 1),
+                       backend="jax", dtype="float32", pipeline=True,
+                       plan_cache=True, gc=True)
+    be = ctx.executor.backend
+    seen = {}
+    run_program = be.run_program
+
+    def spy(program, inputs, placement):
+        seen.setdefault(program.key, (program, [x.shape for x in inputs]))
+        return run_program(program, inputs, placement)
+
+    be.run_program = spy
+    rng = np.random.default_rng(0)
+    y = (rng.random((8 * rows, 1)) < 0.5).astype(np.float64)
+    X = rng.standard_normal((8 * rows, HIGGS_BLOCK[1])) + 0.25 * (y - 0.5)
+    GLM(ctx, max_iter=10, tol=1.0, reg=1e-6).fit(
+        ctx.from_numpy(X, grid=(8, 1)), ctx.from_numpy(y, grid=(8, 1)))
+    assert len(seen) >= 8
+    largest = 0
+    for program, shapes in seen.values():
+        shapes = [tuple(HIGGS_BLOCK[0] if d == rows else d for d in s)
+                  for s in shapes]
+        mem = _compile(jax_backend._program_fn(program), one_chip,
+                       *shapes).memory_analysis()
+        largest = max(largest, mem.argument_size_in_bytes
+                      + mem.output_size_in_bytes + mem.temp_size_in_bytes)
+    assert 2e9 < largest < 16e9  # X^T (w X) reads X and C: 2.8 GB
