@@ -22,6 +22,11 @@ dtype: jax defaults to float32; requesting ``float64`` enables jax's
 process-global x64 mode (``jax.config.update("jax_enable_x64", True)``) so
 the backend can be bit-comparable to the numpy reference — see
 ``ArrayContext``'s dtype documentation for the trade-off.
+
+Precision: a block op with a float32 operand is traced under
+``jax.default_matmul_precision("highest")``, on the per-op path and inside
+segment programs alike, so its contractions compute in float32 and not in
+XLA's TPU default of one bfloat16 pass.  float64 ops keep their lowering.
 """
 from __future__ import annotations
 
@@ -181,13 +186,30 @@ class JaxBackend(BlockBackend):
 
     def _lowering(self, op: str, meta: Dict[str, Any],
                   inputs: Sequence[Any]) -> Callable:
-        fn = self._route(op, inputs)[1](op, meta)
+        build = self._route(op, inputs)[1]
+        fn = build(op, meta)
         if fn is None:
             # no silent host round-trip: it would hide the device on the
             # chip path behind numpy
             raise NotImplementedError(
                 f"{self.name} backend has no lowering for block op {op!r}")
+        if build == self._build and any(x.dtype == np.float32 for x in inputs):
+            # a float32 block op computes in float32, as numpy does: XLA's
+            # default for a float32 contraction on a TPU is one bfloat16
+            # pass, so every product in the lowering is traced at HIGHEST
+            # (matmul, tensordot, einsum, syrk_update and the products of
+            # the solves); other ops have no product to change
+            return self._at_highest(fn)
         return fn
+
+    def _at_highest(self, fn: Callable) -> Callable:
+        precision = self._jax.default_matmul_precision
+
+        def highest(*xs):
+            with precision("highest"):
+                return fn(*xs)
+
+        return highest
 
     def _program_fn(self, program: Program) -> Callable:
         barrier = self._jax.lax.optimization_barrier
@@ -309,7 +331,9 @@ class JaxBackend(BlockBackend):
             return lambda x: jnp.moveaxis(x, mode, 0).reshape(
                 x.shape[mode], -1)
         if op == "khatri_rao":
-            return lambda a, b: jnp.einsum("jf,kf->jkf", a, b).reshape(
+            # a broadcast product, one rounding per element as in numpy
+            # (``einsum`` would lower it to an outer-product contraction)
+            return lambda a, b: (a[:, None, :] * b[None, :, :]).reshape(
                 a.shape[0] * b.shape[0], a.shape[1])
         if op == "solve":
             return lambda h, g: jnp.linalg.solve(h, g)

@@ -214,6 +214,8 @@ class ArrayContext:
                 "drain_s": st.drain_s,
                 "reshards": st.reshards,
                 "reshard_moved": st.reshard_moved_elements,
+                "reshard_s": st.reshard_s,
+                "layout_bytes": self.executor.stats.layout_bytes,
                 "pygc_s": PYGC.seconds,
                 "pygc_gen2": PYGC.full,
             }

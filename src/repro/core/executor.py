@@ -67,6 +67,8 @@ from .memory import MemoryManager
 from .trace import SPAN_DRAIN, Span
 
 _MODES = ("numpy", "sim", "jax", "pallas")
+# block ops that only move elements between layouts (reshards, unfoldings)
+_LAYOUT_OPS = frozenset(("slice", "concat_blocks", "matricize"))
 
 
 @dataclass
@@ -144,6 +146,7 @@ class ExecStats:
     n_flushes: int = 0      # event-loop drains
     peak_queue: int = 0     # max total ops pending at once
     drain_s: float = 0.0    # wall time inside flush() — pipelined queue drain
+    layout_bytes: int = 0   # bytes written by the _LAYOUT_OPS as they run
 
     def reset(self) -> None:
         self.n_rfc = 0
@@ -152,6 +155,7 @@ class ExecStats:
         self.n_flushes = 0
         self.peak_queue = 0
         self.drain_s = 0.0
+        self.layout_bytes = 0
 
 
 class Executor:
@@ -213,6 +217,7 @@ class Executor:
 
             self.backend = make_backend(mode, dtype=dtype, devices=devices)
             self.dtype = self.backend.dtype
+        self._itemsize = np.dtype(self.dtype).itemsize
         # block residency manager: peak accounting always on; refcount GC,
         # spill/recompute eviction and per-node budgets activate via
         # ``memory.configure`` (ArrayContext's gc/mem_capacity parameters)
@@ -509,6 +514,8 @@ class Executor:
         else:
             out = self.backend.execute(op, meta, ins, placement)
         self.store[out_id] = out
+        if op in _LAYOUT_OPS:
+            self.stats.layout_bytes += out_elements * self._itemsize
         self.memory.on_materialize(out_id, placement[0], out_elements)
         self.memory.unpin(in_ids)
         if tag is not None and out.build.left == 0:

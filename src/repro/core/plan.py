@@ -523,6 +523,7 @@ class SchedStats:
     reshards: int = 0
     reshard_ops: int = 0
     reshard_moved_elements: float = 0.0
+    reshard_s: float = 0.0      # wall time inside reshard calls (nums:reshard)
     # backend compile-cache accounting (``repro.backend``): snapshot of the
     # active backend's structural compile cache + dispatch counters, refreshed
     # by ``SchedStats.note_backend`` (``ArrayContext.loads`` calls it) — the
@@ -601,6 +602,7 @@ class SchedStats:
             "reshards": self.reshards,
             "reshard_ops": self.reshard_ops,
             "reshard_moved_elements": self.reshard_moved_elements,
+            "reshard_s": self.reshard_s,
             "backend_compiles": self.backend_compiles,
             "backend_compile_hits": self.backend_compile_hits,
             "backend_compile_misses": self.backend_compile_misses,
@@ -627,6 +629,7 @@ class SchedStats:
         self.reshards = 0
         self.reshard_ops = 0
         self.reshard_moved_elements = 0.0
+        self.reshard_s = 0.0
         self.comm_moved.clear()
         self.comm_lower.clear()
         self.comm_ratios.clear()

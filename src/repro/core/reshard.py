@@ -32,6 +32,7 @@ measured by the same load accounting (see ``benchmarks/bench_tensor.py``).
 """
 from __future__ import annotations
 
+import functools
 import itertools
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -40,6 +41,7 @@ import numpy as np
 from .graph_array import GraphArray, Vertex, infer_shape
 from .grid import ArrayGrid, Index
 from .layout import HierarchicalLayout, NodeGrid, tune_node_grid
+from .trace import SPAN_RESHARD, Span
 
 
 def _axis_starts(grid: ArrayGrid, axis: int) -> List[int]:
@@ -121,6 +123,18 @@ def _resolve_target(
     return dst_grid, ng, table
 
 
+def _spanned(fn):
+    """Run a reshard under the ``nums:reshard`` span, its wall time added to
+    the context's ``SchedStats.reshard_s``."""
+    @functools.wraps(fn)
+    def spanned(ga: GraphArray, *args, **kwargs) -> GraphArray:
+        with Span(SPAN_RESHARD, ga.ctx.sched_stats, "reshard_s"):
+            return fn(ga, *args, **kwargs)
+
+    return spanned
+
+
+@_spanned
 def reshard(
     ga: GraphArray,
     grid: Optional[Sequence[int]] = None,
@@ -175,6 +189,7 @@ def reshard(
     return out
 
 
+@_spanned
 def reshard_naive(
     ga: GraphArray,
     grid: Optional[Sequence[int]] = None,

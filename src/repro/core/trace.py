@@ -95,12 +95,30 @@ span                   region                                ``loads()`` counter
 ``nums:pygc``          one CPython cyclic collection         ``pygc_s``, ``pygc_gen2``
                        (``gc.callbacks``, process-wide)      (full collections)
 ``nums:newton.iter``   one ``NewtonSolver.fit`` iteration    none
+``nums:reshard``       one ``reshard`` or ``reshard_naive``  ``reshard_s``
+                       call (``core/reshard.py``)
+``nums:cpals.layout``  a CP-ALS fit's three mode layouts     none
+                       and unfoldings (``factor/cpals.py``)
+``nums:cpals.sweep``   one CP-ALS sweep over the three       none
+                       modes
 =====================  ====================================  ===========================
+
+One counter has no span: ``layout_bytes`` in ``loads()`` adds the output
+bytes of every ``slice``, ``concat_blocks`` and ``matricize`` block op as
+it runs (``Executor._execute``), on the per-op path and inside lowered
+programs alike.
 
 They nest: ``fingerprint``, ``replay`` and ``schedule`` lie in
 ``compute``; ``dispatch``, ``compile`` and ``move`` lie in ``drain`` (in
 ``compute`` itself for a synchronous executor); ``pygc`` can fall inside any
-span.  Capture them with the JAX profiler around any program::
+span.  A ``reshard`` holds the ``compute`` calls that schedule its source
+and its move graph, and lies in ``cpals.layout`` or ``cpals.sweep`` when
+CP-ALS calls it; ``cpals.layout`` and each ``cpals.sweep`` hold the
+``compute`` calls of their block ops.  A pipelined executor drains when a
+value is read, so in a CP-ALS fit ``drain`` lies outside ``cpals.layout``
+and ``cpals.sweep`` (the factors are read after ``cp_als`` returns); a
+synchronous executor runs each op inside its ``compute``.  Capture them
+with the JAX profiler around any program::
 
     with jax.profiler.trace("prof"):
         GLM(ctx).fit(X, y)
@@ -133,6 +151,9 @@ SPAN_SYNC = "nums:sync"
 SPAN_MOVE = "nums:move"
 SPAN_PYGC = "nums:pygc"
 SPAN_NEWTON_ITER = "nums:newton.iter"
+SPAN_RESHARD = "nums:reshard"
+SPAN_CPALS_LAYOUT = "nums:cpals.layout"
+SPAN_CPALS_SWEEP = "nums:cpals.sweep"
 
 
 class _NoProfiler:

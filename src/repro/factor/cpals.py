@@ -19,13 +19,16 @@ reshard subsystem makes those layouts reachable:
   leaves the cluster; the whole sweep works on the metadata-only ``sim``
   backend for load studies.
 
+A fit's layouts and unfoldings are built under the ``nums:cpals.layout``
+span and each sweep runs under ``nums:cpals.sweep`` (``core/trace.py``).
+
 ``cp_als_reference`` is the pure-numpy mirror (same update order, same
 initialization) used by the accuracy tests (1e-8 agreement).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -33,6 +36,7 @@ from repro.core import GraphArray
 from repro.core.graph_array import Vertex, infer_shape
 from repro.core.grid import ArrayGrid
 from repro.core.reshard import reshard as _reshard, reshard_naive as _reshard_naive
+from repro.core.trace import SPAN_CPALS_LAYOUT, SPAN_CPALS_SWEEP, Span
 
 
 # ---------------------------------------------------------------------------
@@ -108,6 +112,38 @@ def _mode_grid(x: GraphArray, mode: int, q: int) -> Tuple[int, ...]:
     return tuple(q if a == mode else 1 for a in range(x.ndim))
 
 
+# the two other modes of each mode, in Khatri-Rao order
+_OTHERS = {0: (1, 2), 1: (0, 2), 2: (0, 1)}
+
+
+def _unfoldings(X: GraphArray, move) -> List[GraphArray]:
+    """One layout and unfolding per mode, built once a fit and reused every
+    sweep.  The resharded copies are dropped as this returns: each is freed
+    once its unfolding has been computed from it."""
+    q = max(X.grid.grid)
+    xmats = []
+    for mode in range(3):
+        tgrid = _mode_grid(X, mode, q)
+        Xi = X if X.grid.grid == tgrid else move(X, grid=tgrid)
+        xmats.append(matricize(Xi, mode).compute())
+    return xmats
+
+
+def _sweep(factors: List[GraphArray], xmats: Sequence[GraphArray],
+           move) -> None:
+    """One ALS sweep: each mode's factor, in turn, from its MTTKRP and the
+    Hadamard of the other two factors' Grams."""
+    for mode in range(3):
+        o1, o2 = (factors[m] for m in _OTHERS[mode])
+        kr = khatri_rao(o1, o2)
+        M = xmats[mode] @ kr
+        G = (_gram(o1) * _gram(o2)).compute()
+        updated = _blockwise_rsolve(M, G).compute()
+        # gather the row-partitioned update back to a single block for
+        # the next mode's Khatri-Rao — the in-loop (plan-cached) reshard
+        factors[mode] = move(updated, grid=(1, 1))
+
+
 def cp_als(
     X: GraphArray,
     rank: int,
@@ -138,7 +174,6 @@ def cp_als(
     move = _reshard if method == "reshard" else _reshard_naive
     ctx = X.ctx
     dims = X.shape
-    q = max(X.grid.grid)
     if inits is None:
         rng = np.random.default_rng(seed)
         inits = [rng.standard_normal((d, rank)) for d in dims]
@@ -148,26 +183,13 @@ def cp_als(
     stats = ctx.sched_stats
     moved0, reshards0 = stats.reshard_moved_elements, stats.reshards
 
-    # one layout + unfolding per mode, built once and reused every sweep
-    xmats = []
-    for mode in range(3):
-        tgrid = _mode_grid(X, mode, q)
-        Xi = X if X.grid.grid == tgrid else move(X, grid=tgrid)
-        xmats.append(matricize(Xi, mode).compute())
-
-    others = {0: (1, 2), 1: (0, 2), 2: (0, 1)}
+    with Span(SPAN_CPALS_LAYOUT):
+        xmats = _unfoldings(X, move)
     result = CPALSResult(factors=factors, iterations=0,
                          moved_elements=0.0, reshards=0)
-    for _sweep in range(iters):
-        for mode in range(3):
-            o1, o2 = (factors[m] for m in others[mode])
-            kr = khatri_rao(o1, o2)
-            M = xmats[mode] @ kr
-            G = (_gram(o1) * _gram(o2)).compute()
-            updated = _blockwise_rsolve(M, G).compute()
-            # gather the row-partitioned update back to a single block for
-            # the next mode's Khatri-Rao — the in-loop (plan-cached) reshard
-            factors[mode] = move(updated, grid=(1, 1))
+    for _ in range(iters):
+        with Span(SPAN_CPALS_SWEEP):
+            _sweep(factors, xmats, move)
         result.iterations += 1
         if track_fit and ctx.executor.mode == "numpy":
             result.fit_history.append(cp_fit(X, factors))
@@ -212,10 +234,9 @@ def cp_als_reference(
         rng = np.random.default_rng(seed)
         inits = [rng.standard_normal((d, rank)) for d in X.shape]
     factors = [np.asarray(f0, dtype=np.float64) for f0 in inits]
-    others = {0: (1, 2), 1: (0, 2), 2: (0, 1)}
-    for _sweep in range(iters):
+    for _ in range(iters):
         for mode in range(3):
-            o1, o2 = (factors[m] for m in others[mode])
+            o1, o2 = (factors[m] for m in _OTHERS[mode])
             M = _unfold_np(X, mode) @ _khatri_rao_np(o1, o2)
             G = (o1.T @ o1) * (o2.T @ o2)
             factors[mode] = np.linalg.solve(G.T, M.T).T

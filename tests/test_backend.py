@@ -474,6 +474,61 @@ def test_chaos_kill_mid_flush_replays_through_jax_backend():
     assert ctx.executor.backend.stats.as_dict()["backend_replays"] > 0
 
 
+# ---------------------------------------------------------------------------
+# contraction precision
+# ---------------------------------------------------------------------------
+
+# block ops with a product inside: (meta, operand shapes)
+_CONTRACTIONS = {
+    "matmul": ({"tb": True}, [(6, 4), (5, 4)]),
+    "tensordot": ({"axes": 1}, [(6, 4), (4, 5)]),
+    "einsum": ({"spec": "ij,jk->ik"}, [(6, 4), (4, 5)]),
+    "syrk_update": ({}, [(6, 6), (6, 4), (6, 4)]),
+    "rsolve": ({}, [(6, 4), (4, 4)]),
+}
+
+
+def _traced_lowering(dtype, op, plain=False):
+    """The jaxpr and the lowered HLO text of the jax backend's lowering of
+    ``op`` on ``dtype`` operands (``plain``: of its ``_build`` alone)."""
+    import jax
+
+    meta, shapes = _CONTRACTIONS[op]
+    be = make_backend("jax", dtype=dtype)
+    rng = np.random.default_rng(0)
+    xs = [jax.numpy.asarray(rng.standard_normal(s) + 4.0 * np.eye(*s), dtype=dtype)
+          for s in shapes]
+    fn = be._build(op, meta) if plain else be._lowering(op, meta, xs)
+    return str(jax.make_jaxpr(fn)(*xs)), jax.jit(fn).lower(*xs).as_text()
+
+
+@pytest.fixture
+def x64_restored():
+    import jax
+
+    was = jax.config.jax_enable_x64
+    yield
+    jax.config.update("jax_enable_x64", was)
+
+
+@pytest.mark.parametrize("op", sorted(_CONTRACTIONS))
+def test_float32_contractions_lower_at_highest(op):
+    """Every product of a float32 block op is traced at HIGHEST; on the CPU
+    ``rsolve`` calls LAPACK, so its products show in the jaxpr only."""
+    jaxpr, hlo = _traced_lowering("float32", op)
+    dots = jaxpr.count("dot_general")
+    assert dots >= 1
+    assert jaxpr.count("precision=(Precision.HIGHEST, Precision.HIGHEST)") == dots
+    if op != "rsolve":
+        assert "precision = [HIGHEST, HIGHEST]" in hlo
+
+
+@pytest.mark.parametrize("op", sorted(_CONTRACTIONS))
+def test_float64_contractions_keep_their_lowering(x64_restored, op):
+    assert (_traced_lowering("float64", op)
+            == _traced_lowering("float64", op, plain=True))
+
+
 def test_sim_mode_has_no_backend():
     from repro.core.executor import Executor
 

@@ -129,3 +129,101 @@ class TestCPALS:
         ctx = _ctx(backend="sim")
         A = build_workload(ctx, "cpals", scale=1, iters=2)
         assert A.shape[0] == 32
+
+
+def _cell_ctx(**kw):
+    """The one-chip CP-ALS cell's context: one node of 8 workers, jax
+    backend in float32, pipelined, plan cache and refcount GC on."""
+    kw.setdefault("trace", False)
+    return ArrayContext(cluster=ClusterSpec(1, 8), node_grid=(1, 1, 1),
+                        backend="jax", dtype="float32", seed=0,
+                        pipeline=True, plan_cache=True, gc=True, **kw)
+
+
+def _planted(n=16, rank=3, seed=4):
+    """A rank-``rank`` tensor plus noise at 10 % of its norm, and inits."""
+    rng = np.random.default_rng(seed)
+    A, B, C = (rng.standard_normal((n, rank)) for _ in range(3))
+    Xn = np.einsum("if,jf,kf->ijk", A, B, C)
+    Xn += 0.1 * np.linalg.norm(Xn) / Xn.size ** 0.5 * rng.standard_normal(Xn.shape)
+    Xn = Xn.astype(np.float32)
+    inits = [rng.standard_normal((n, rank)).astype(np.float32) for _ in range(3)]
+    return Xn, inits
+
+
+def _fit(ctx, Xn, inits, iters=4):
+    X = ctx.from_numpy(Xn, grid=(8, 1, 1))
+    res = cp_als(X, rank=inits[0].shape[1], iters=iters, inits=inits,
+                 track_fit=False)
+    return [f.to_numpy() for f in res.factors]
+
+
+class TestCPALSOnJax:
+    """CP-ALS as the one-chip cell runs it: every cached plan a segment
+    program (``core/plan.py``), float32 contractions at HIGHEST."""
+
+    def test_matches_reference_1e5(self):
+        Xn, inits = _planted()
+        ctx = _cell_ctx()
+        got = _fit(ctx, Xn, inits)
+        ref = cp_als_reference(Xn, rank=3, iters=4,
+                               inits=[f.astype(np.float64) for f in inits])
+        assert ctx.loads()["backend_lowered_ops"] > 0
+        for f, r in zip(got, ref):
+            assert np.linalg.norm(f - r) <= 1e-5 * np.linalg.norm(r)
+
+    def test_segment_programs_are_the_per_op_path_bit_for_bit(self):
+        Xn, inits = _planted(seed=5)
+        lowered_ctx, per_op_ctx = _cell_ctx(), _cell_ctx(trace=True)
+        lowered, per_op = _fit(lowered_ctx, Xn, inits), _fit(per_op_ctx, Xn, inits)
+        assert lowered_ctx.loads()["backend_programs"] > 0
+        assert per_op_ctx.loads()["backend_programs"] == 0
+        for a, b in zip(lowered, per_op):
+            assert a.tobytes() == b.tobytes()
+
+    def test_a_fit_frees_its_unfoldings_as_it_returns(self):
+        """Device bytes alive after each fit, with the cyclic collector
+        off: the second fit leaves what the first left, so no fit's
+        unfoldings or resharded copies wait for a collection."""
+        import gc
+
+        import jax
+
+        Xn, inits = _planted(seed=6)
+        ctx = _cell_ctx()
+        X = ctx.from_numpy(Xn, grid=(8, 1, 1))
+
+        def fit():
+            res = cp_als(X, rank=3, iters=2, inits=inits, track_fit=False)
+            return [f.to_numpy() for f in res.factors]
+
+        gc.collect()
+        gc.disable()
+        try:
+            base = sum(a.nbytes for a in jax.live_arrays())
+            live = []
+            for _ in range(2):
+                fit()
+                live.append(sum(a.nbytes for a in jax.live_arrays()) - base)
+        finally:
+            gc.enable()
+        assert live == [0, 0]
+        assert ctx.executor.memory.total_live == Xn.size
+
+    @pytest.mark.parametrize("backend", ["numpy", "jax"])
+    def test_layout_bytes_are_the_layout_ops_output(self, backend):
+        """Per fit: two reshards of the tensor, each writing it once as
+        slices and once as concatenated blocks; three unfoldings; one factor
+        gather (a concatenation) per mode and sweep."""
+        n, F, iters = 16, 3, 2
+        Xn, inits = _planted(n, F)
+        ctx = (_cell_ctx() if backend == "jax" else
+               ArrayContext(cluster=ClusterSpec(1, 8), node_grid=(1, 1, 1),
+                            backend="numpy"))
+        X = ctx.from_numpy(Xn, grid=(8, 1, 1))
+        before = ctx.loads()["layout_bytes"]
+        res = cp_als(X, rank=F, iters=iters, inits=inits, track_fit=False)
+        [f.to_numpy() for f in res.factors]
+        itemsize = np.dtype(ctx.dtype).itemsize
+        expect = itemsize * (7 * n ** 3 + 3 * iters * n * F)
+        assert ctx.loads()["layout_bytes"] - before == expect

@@ -58,7 +58,7 @@ SUMMARY_KEYS = [
 RUNTIME_KEYS = [
     "n_rfc", "transfers", "makespan", "pending_ops", "plan_hits",
     "plan_misses", "sched_overhead_s", "dispatch_s", "drain_s", "reshards",
-    "reshard_moved", "pygc_s", "pygc_gen2",
+    "reshard_moved", "reshard_s", "layout_bytes", "pygc_s", "pygc_gen2",
 ]
 BACKEND_KEYS = [
     "backend_dispatches", "backend_dispatch_s", "backend_jit_calls",

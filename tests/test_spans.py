@@ -213,3 +213,54 @@ def test_move_bytes_are_the_moved_operands_nbytes():
     counted, summed = map(int, lines["BYTES"].split())
     assert moves == n_moved > 0
     assert counted == summed > 0
+
+
+@pytest.fixture(scope="module")
+def traced_cpals(tmp_path_factory):
+    """A warm CP-ALS fit of two sweeps, factors read, under
+    ``jax.profiler.trace``: its spans and the growth of ``reshard_s``."""
+    import jax
+
+    from repro.factor import cp_als
+
+    rng = np.random.default_rng(8)
+    ctx = ArrayContext(cluster=ClusterSpec(1, 8), node_grid=(1, 1, 1),
+                       backend="jax", pipeline=True, plan_cache=True, gc=True)
+    X = ctx.from_numpy(rng.standard_normal((16, 16, 16)), grid=(8, 1, 1))
+
+    def fit():
+        res = cp_als(X, rank=3, iters=2, seed=1, track_fit=False)
+        return [f.to_numpy() for f in res.factors]
+
+    fit()  # compiles and fills the plan cache
+    log_dir = str(tmp_path_factory.mktemp("prof_cpals"))
+    before = ctx.loads()["reshard_s"]
+    with jax.profiler.trace(log_dir):
+        fit()
+    return _host_spans(log_dir), ctx.loads()["reshard_s"] - before
+
+
+def _inside(span, parents):
+    s, e = span
+    return any(ps <= s and e <= pe for ps, pe in parents)
+
+
+def test_cpals_spans_nest_as_documented(traced_cpals):
+    spans, _ = traced_cpals
+    layout, sweeps = spans["nums:cpals.layout"], spans["nums:cpals.sweep"]
+    assert len(layout) == 1 and len(sweeps) == 2
+    # two reshards of the tensor, then one factor gather per mode and sweep
+    assert len(spans["nums:reshard"]) == 2 + 3 * 2
+    cpals = layout + sweeps
+    assert all(_inside(r, cpals) for r in spans["nums:reshard"])
+    assert all(_inside(c, cpals) for c in spans["nums:compute"])
+    # the pipelined executor drains when the factors are read
+    assert spans["nums:drain"]
+    assert not any(_inside(d, cpals) for d in spans["nums:drain"])
+
+
+def test_reshard_spans_match_reshard_s(traced_cpals):
+    spans, counted_s = traced_cpals
+    traced_s = sum(e - s for s, e in spans["nums:reshard"]) / 1e9
+    assert counted_s > 0
+    assert abs(traced_s - counted_s) <= 0.1 * counted_s, (traced_s, counted_s)

@@ -13,6 +13,8 @@ from jax.sharding import SingleDeviceSharding
 
 # one of the eight row blocks of an 11 000 000 x 28 HIGGS-shaped design matrix
 HIGGS_BLOCK = (1_375_000, 28)
+# the CP-ALS cell: a 768^3 float32 tensor in eight mode-0 slabs, rank 64
+CPALS_N, CPALS_SLABS, CPALS_RANK = 768, 8, 64
 
 
 @pytest.fixture(scope="module")
@@ -111,3 +113,75 @@ def test_lowered_newton_programs_compile(one_chip, jax_backend):
         largest = max(largest, mem.argument_size_in_bytes
                       + mem.output_size_in_bytes + mem.temp_size_in_bytes)
     assert 2e9 < largest < 16e9  # X^T (w X) reads X and C: 2.8 GB
+
+
+def test_lowered_cpals_programs_compile(one_chip, jax_backend):
+    """Every segment program of a CP-ALS fit (the plans the one-chip CP-ALS
+    cell replays) compiles at the cell's slab shapes, each product in it at
+    HIGHEST, and the largest fits one chip.
+
+    The programs come from a fit of a 16^3 tensor in the cell's eight slabs
+    at rank 3: each coordinate in a slice or concatenation is scaled by 48
+    to 768^3, and each operand dimension taken to its size in the cell.
+    The largest, a layout change of the tensor, reads it (1.81 GB), writes
+    it (1.81 GB) and holds its slices (1.39-1.85 GB of temporaries)."""
+    import re
+
+    import numpy as np
+
+    from repro.backend.base import Program
+    from repro.core import ArrayContext, ClusterSpec
+    from repro.factor import cp_als
+
+    n, rank = 16, 3
+    scale = CPALS_N // n
+    size = {n // CPALS_SLABS: CPALS_N // CPALS_SLABS, n: CPALS_N,
+            n * n: CPALS_N ** 2, rank: CPALS_RANK}
+    ctx = ArrayContext(cluster=ClusterSpec(1, CPALS_SLABS), node_grid=(1, 1, 1),
+                       backend="jax", dtype="float32", pipeline=True,
+                       plan_cache=True, gc=True)
+    be = ctx.executor.backend
+    seen = {}
+    run_program = be.run_program
+
+    def spy(program, inputs, placement):
+        seen.setdefault(program.key, (program, [x.shape for x in inputs]))
+        return run_program(program, inputs, placement)
+
+    be.run_program = spy
+    X = ctx.from_numpy(np.random.default_rng(0).standard_normal((n, n, n)),
+                       grid=(CPALS_SLABS, 1, 1))
+    res = cp_als(X, rank=rank, iters=2, seed=1, track_fit=False)
+    [f.to_numpy() for f in res.factors]
+
+    def scaled(values):
+        return tuple(int(v) * scale for v in values)
+
+    def at_cell_size(program):
+        ops = []
+        for op, meta, args in program.ops:
+            if op == "slice":
+                meta = dict(meta, starts=scaled(meta["starts"]),
+                            stops=scaled(meta["stops"]))
+            elif op == "concat_blocks":
+                meta = dict(meta, shape=scaled(meta["shape"]),
+                            offsets=tuple(scaled(o) for o in meta["offsets"]))
+            ops.append((op, meta, args))
+        return Program(tuple(ops), program.outputs)
+
+    assert len(seen) >= 8
+    largest = 0
+    products = 0
+    for program, shapes in seen.values():
+        shapes = [tuple(size[d] for d in s) for s in shapes]
+        compiled = _compile(jax_backend._program_fn(at_cell_size(program)),
+                            one_chip, *shapes)
+        mem = compiled.memory_analysis()
+        largest = max(largest, mem.argument_size_in_bytes
+                      + mem.output_size_in_bytes + mem.temp_size_in_bytes)
+        for line in compiled.as_text().splitlines():
+            if re.search(r"= \S+ (convolution|dot)\(", line):
+                products += 1
+                assert "operand_precision={highest,highest}" in line, line
+    assert products >= 3  # the Grams, the MTTKRP, the solve
+    assert 5e9 < largest < 16e9
