@@ -52,6 +52,7 @@ class BackendStats:
     replays: int = 0        # lineage-replay re-executions (fault recovery)
     programs: int = 0       # run_program() calls: one lowered segment each
     lowered_ops: int = 0    # block ops run inside those programs
+    gram_ops: int = 0       # weighted-Gram (wgram) block ops run, per op or in programs
 
     def reset(self) -> None:
         self.dispatches = 0
@@ -66,6 +67,7 @@ class BackendStats:
         self.replays = 0
         self.programs = 0
         self.lowered_ops = 0
+        self.gram_ops = 0
 
     def as_dict(self) -> Dict[str, float]:
         return {
@@ -81,6 +83,7 @@ class BackendStats:
             "backend_replays": self.replays,
             "backend_programs": self.programs,
             "backend_lowered_ops": self.lowered_ops,
+            "backend_gram_ops": self.gram_ops,
         }
 
 
@@ -91,15 +94,17 @@ class Program:
     arg ``j >= 0`` is the result of the j-th op, ``~i`` the i-th input.
     ``outputs`` lists the ops whose results the program returns, in order.
     ``key`` is structural (op kinds, canonical metadata, wiring, outputs),
-    so structurally identical segments share one compilation."""
+    so structurally identical segments share one compilation.
+    ``gram_ops`` counts its weighted-Gram ops (``BackendStats.gram_ops``)."""
 
-    __slots__ = ("ops", "outputs", "key")
+    __slots__ = ("ops", "outputs", "key", "gram_ops")
 
     def __init__(self, ops: Tuple[Tuple[str, Dict[str, Any], Tuple[int, ...]], ...],
                  outputs: Tuple[int, ...]):
         self.ops = ops
         self.outputs = outputs
         self.key = program_key(ops, outputs)
+        self.gram_ops = sum(1 for op, _meta, _args in ops if op == "wgram")
 
 
 class BlockBackend:

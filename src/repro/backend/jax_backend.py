@@ -130,6 +130,8 @@ class JaxBackend(BlockBackend):
     def execute(self, op: str, meta: Dict[str, Any], inputs: Sequence[Any],
                 placement: Tuple[int, int]):
         self.stats.dispatches += 1
+        if op == "wgram":
+            self.stats.gram_ops += 1
         inputs = self._colocate(inputs, placement)
         salt, _build = self._route(op, inputs)
         key = structural_key(salt, op, meta, self._signature(inputs))
@@ -148,6 +150,7 @@ class JaxBackend(BlockBackend):
         self.stats.dispatches += 1
         self.stats.programs += 1
         self.stats.lowered_ops += len(program.ops)
+        self.stats.gram_ops += program.gram_ops
         inputs = self._colocate(inputs, placement)
         key = (self.name, program.key, self._signature(inputs))
         return self._call(key, inputs, placement, "program",
@@ -197,8 +200,8 @@ class JaxBackend(BlockBackend):
             # a float32 block op computes in float32, as numpy does: XLA's
             # default for a float32 contraction on a TPU is one bfloat16
             # pass, so every product in the lowering is traced at HIGHEST
-            # (matmul, tensordot, einsum, syrk_update and the products of
-            # the solves); other ops have no product to change
+            # (matmul, tensordot, einsum, wgram, syrk_update and the
+            # products of the solves); other ops have no product to change
             return self._at_highest(fn)
         return fn
 
@@ -347,6 +350,11 @@ class JaxBackend(BlockBackend):
             return lambda a, l: jnp.linalg.solve(l, a.T).T
         if op == "syrk_update":
             return lambda c, a, b: c - a @ b.T
+        if op == "wgram":
+            # the weighting multiply fuses into the contraction's operand:
+            # no (m, d) product of x and w is written out
+            dims = (((0,), (0,)), ((), ()))
+            return lambda x, w: self._jax.lax.dot_general(x * w, x, dims)
         if op == "svd_u":
             return lambda x: jnp.linalg.svd(x, full_matrices=False)[0]
         if op == "svd_s":

@@ -30,4 +30,6 @@ class NumpyBackend(BlockBackend):
     def execute(self, op: str, meta: Dict[str, Any], inputs: Sequence[Any],
                 placement: Tuple[int, int]):
         self.stats.dispatches += 1
+        if op == "wgram":
+            self.stats.gram_ops += 1
         return execute_block_op(op, meta, [np.asarray(x) for x in inputs])

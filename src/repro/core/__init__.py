@@ -11,7 +11,7 @@ from .cluster import ClusterState, CostModel, WorkerClocks, MEM, NET_IN, NET_OUT
 from .context import ArrayContext
 from .executor import Executor
 from .fusion import fuse_graph
-from .graph_array import GraphArray, einsum, matmul, tensordot
+from .graph_array import GraphArray, einsum, matmul, tensordot, weighted_gram
 from .grid import ArrayGrid, auto_grid
 from .memory import MemoryManager, MemStats
 from .layout import (
@@ -69,6 +69,7 @@ __all__ = [
     "reshard_naive",
     "tensordot",
     "tune_node_grid",
+    "weighted_gram",
     "MEM",
     "NET_IN",
     "NET_OUT",

@@ -225,6 +225,9 @@ def execute_block_op(op: str, meta: Dict[str, Any], inputs: Sequence[np.ndarray]
     if op == "syrk_update":  # trailing update C - A B^T (syrk when A is B)
         c, a, b = inputs
         return c - a @ b.T
+    if op == "wgram":  # weighted Gram x^T diag(w) x of a row block (§6 Hessian)
+        x, w = inputs
+        return (x * w).T @ x
     if op == "svd_u":  # thin-SVD factors of a small-core block (rSVD §8.3)
         return np.linalg.svd(inputs[0], full_matrices=False)[0]
     if op == "svd_s":
@@ -313,6 +316,9 @@ def infer_shape(op: str, meta: Dict[str, Any], in_shapes: Sequence[Tuple[int, ..
         return tuple(in_shapes[0])
     if op == "syrk_update":
         return tuple(in_shapes[0])
+    if op == "wgram":
+        d = in_shapes[0][1]
+        return (d, d)
     if op == "svd_u":
         m, n = in_shapes[0]
         return (m, min(m, n))
@@ -707,6 +713,28 @@ def matmul(a: Union[GraphArray, TransposedView], b: Union[GraphArray, Transposed
             parts.append(Vertex("op", "matmul", shp, [ca, cb], dict(meta)))
         blocks[oidx] = _reduce_or_single(parts)
     return GraphArray(ctx, out_grid, blocks)
+
+
+def weighted_gram(X: GraphArray, w: GraphArray) -> GraphArray:
+    """``X^T diag(w) X`` for an (n, d) ``X`` in row blocks and its (n, 1)
+    weights ``w`` in the same row blocks: one ``wgram`` block op per row
+    block, summed over the same reduce tree as ``X.T @ (w * X)``, with no
+    n x d product ever formed."""
+    if X.ndim != 2 or X.grid.grid[1] != 1:
+        raise ValueError(f"weighted_gram needs X in row blocks, got grid {X.grid.grid}")
+    if w.shape != (X.shape[0], 1) or w.grid.grid != (X.grid.grid[0], 1):
+        raise ValueError(
+            f"weighted_gram needs (n, 1) weights in X's row blocks: "
+            f"{w.shape}/{w.grid.grid} against {X.shape}/{X.grid.grid}")
+    parts = []
+    for h in range(X.grid.grid[0]):
+        cx, cw = X.block((h, 0)), w.block((h, 0))
+        parts.append(Vertex("op", "wgram", infer_shape("wgram", {}, [cx.shape, cw.shape]),
+                            [cx, cw]))
+    d = X.shape[1]
+    blocks = np.empty((1, 1), dtype=object)
+    blocks[0, 0] = _reduce_or_single(parts)
+    return GraphArray(X.ctx, ArrayGrid((d, d), (1, 1), X.grid.dtype), blocks)
 
 
 def tensordot(a: GraphArray, b: GraphArray, axes: int) -> GraphArray:

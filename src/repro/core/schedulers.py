@@ -156,7 +156,7 @@ class SchedulerBase:
         subsystem, whose pieces may be cached on several nodes — offer the
         union of all nodes on which any operand resides."""
         homes = [state.home[c.vid][0] for c in v.children]
-        if v.op in ("matmul", "tensordot", "einsum", "concat_blocks"):
+        if v.op in ("matmul", "tensordot", "einsum", "wgram", "concat_blocks"):
             opts: Set[int] = set()
             for c in v.children:
                 opts |= state.nodes_of(c.vid)

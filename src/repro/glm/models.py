@@ -3,10 +3,11 @@
 Each model supplies, in GraphArray expressions:
   mean(X, beta)            the model m(X, beta)
   gradient(X, y, mu)       ∇f = X^T (mu - y)          (canonical links)
-  hessian_weights(mu)      w with  ∇²f = X^T (w × X)
+  hessian_weights(mu)      w with  ∇²f = X^T diag(w) X
   objective(X, y, beta)    the convex objective f
 All expressions follow the §6 schedule: elementwise ops stay local; the
-X^T(...) contractions are block-wise inner products reduced over a tree.
+X^T(...) contractions and the weighted Gram X^T diag(w) X are block-wise
+products reduced over a tree.
 """
 from __future__ import annotations
 

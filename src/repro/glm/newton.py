@@ -3,9 +3,11 @@
 Per iteration:
     mu   = m(X, beta)                      elementwise after X@beta: local
     g    = X^T (mu - y) + reg*beta         blockwise inner product -> tree
-    H    = X^T ((w x X)) + reg*I           blockwise inner product -> tree
+    H    = X^T diag(w) X + reg*I           blockwise weighted Gram -> tree
     beta = beta - H^{-1} g                 single-block solve on node N_0,0
-The convergence test ||g||_2 <= eps is computed on the single-block gradient.
+Each row block's share of H is one ``wgram`` block op (``weighted_gram``),
+so no n x d product of w and X is ever formed.  The convergence test
+||g||_2 <= eps is computed on the single-block gradient.
 """
 from __future__ import annotations
 
@@ -16,7 +18,7 @@ import numpy as np
 
 from repro.core import ArrayContext, GraphArray
 from repro.core.grid import ArrayGrid
-from repro.core.graph_array import Vertex
+from repro.core.graph_array import Vertex, weighted_gram
 from repro.core.trace import SPAN_NEWTON_ITER, Span
 
 
@@ -62,8 +64,7 @@ class NewtonSolver:
                 if self.reg > 0:
                     g = (g + self.reg * beta).compute()
                 w = model.hessian_weights(mu).compute()
-                C = (w * X).compute()
-                H = (X.T @ C).compute()
+                H = weighted_gram(X, w).compute()
                 if eye is not None:
                     H = (H + eye).compute()
                 gnorm = float(np.sqrt((g * g).sum().to_numpy()))

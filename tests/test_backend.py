@@ -108,6 +108,7 @@ def _op_cases():
     cases.append(("matricize", {"mode": 1}, [t]))
     cases.append(("khatri_rao", {}, [rng.standard_normal((3, 4)),
                                      rng.standard_normal((2, 4))]))
+    cases.append(("wgram", {}, [x, rng.random((6, 1)) + 0.1]))
     spd = rng.standard_normal((4, 4))
     spd = spd @ spd.T + 4.0 * np.eye(4)
     cases.append(("solve", {}, [spd, rng.standard_normal((4, 2))]))
@@ -484,6 +485,7 @@ _CONTRACTIONS = {
     "tensordot": ({"axes": 1}, [(6, 4), (4, 5)]),
     "einsum": ({"spec": "ij,jk->ik"}, [(6, 4), (4, 5)]),
     "syrk_update": ({}, [(6, 6), (6, 4), (6, 4)]),
+    "wgram": ({}, [(6, 4), (6, 1)]),
     "rsolve": ({}, [(6, 4), (4, 4)]),
 }
 

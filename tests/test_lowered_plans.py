@@ -201,7 +201,7 @@ def four_devices():
         sys.path.insert(0, {os.path.join(REPO, "src")!r})
         import jax
         import numpy as np
-        from repro.core import ArrayContext, ClusterSpec
+        from repro.core import ArrayContext, ClusterSpec, weighted_gram
         from repro.glm import GLM
 
         moved = []
@@ -233,7 +233,16 @@ def four_devices():
                              "seen": len(moved) - n0,
                              **{{k: loads[k] for k in (
                                  "backend_programs", "backend_lowered_ops",
-                                 "backend_device_moves", "makespan")}}}}
+                                 "backend_gram_ops", "backend_device_moves",
+                                 "makespan")}}}}
+            ctx = ArrayContext(cluster=ClusterSpec(4, 2), node_grid=(4, 1),
+                               backend=backend)
+            w = rng.random((8_000, 1))
+            H = weighted_gram(ctx.from_numpy(X, grid=(8, 1)),
+                              ctx.from_numpy(w, grid=(8, 1))).to_numpy()
+            res["gram_err"] = float(np.abs(H - (X * w).T @ X).max()
+                                    / np.abs((X * w).T @ X).max())
+            res["gram_ops"] = ctx.loads()["backend_gram_ops"]
             out[backend] = res
         print("RESULT", json.dumps(out))
     """
@@ -259,3 +268,12 @@ def test_four_devices_lowered_is_bitwise_with_fewer_calls(four_devices, backend)
     for run in (lowered, per_op):
         assert run["backend_device_moves"] == run["seen"] > 0
     assert lowered["backend_device_moves"] < per_op["backend_device_moves"]
+    # the Hessian is one weighted-Gram op per row block and iteration
+    assert lowered["backend_gram_ops"] == per_op["backend_gram_ops"] == 8 * 3
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_four_devices_weighted_gram_matches_numpy(four_devices, backend):
+    res = four_devices[backend]
+    assert res["gram_err"] < 1e-5  # float32 blocks
+    assert res["gram_ops"] == 8

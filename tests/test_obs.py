@@ -64,7 +64,7 @@ BACKEND_KEYS = [
     "backend_dispatches", "backend_dispatch_s", "backend_jit_calls",
     "backend_h2d", "backend_d2h", "backend_sync_s", "backend_device_moves",
     "backend_device_move_bytes", "backend_fallbacks", "backend_replays",
-    "backend_programs", "backend_lowered_ops",
+    "backend_programs", "backend_lowered_ops", "backend_gram_ops",
 ]
 MEM_KEYS = [
     "mem_capacity", "mem_high_watermark", "mem_low_watermark",

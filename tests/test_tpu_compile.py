@@ -13,6 +13,7 @@ from jax.sharding import SingleDeviceSharding
 
 # one of the eight row blocks of an 11 000 000 x 28 HIGGS-shaped design matrix
 HIGGS_BLOCK = (1_375_000, 28)
+HIGGS_COLUMN = (HIGGS_BLOCK[0], 1)  # a row block of y, mu, w or mu - y
 # the CP-ALS cell: a 768^3 float32 tensor in eight mode-0 slabs, rank 64
 CPALS_N, CPALS_SLABS, CPALS_RANK = 768, 8, 64
 
@@ -63,17 +64,34 @@ def test_pallas_matmul_compiles_to_mosaic(one_chip):
 
 
 @pytest.mark.parametrize("op, meta, shapes", [
-    ("matmul", {"ta": True}, [HIGGS_BLOCK, HIGGS_BLOCK]),   # X^T (w X)
+    ("wgram", {}, [HIGGS_BLOCK, HIGGS_COLUMN]),             # X^T diag(w) X
+    ("matmul", {"ta": True}, [HIGGS_BLOCK, HIGGS_COLUMN]),  # X^T (mu - y)
     ("matmul", {}, [HIGGS_BLOCK, (28, 1)]),                 # X @ beta
-    ("sigmoid", {}, [(HIGGS_BLOCK[0], 1)]),                 # mu
+    ("sigmoid", {}, [HIGGS_COLUMN]),                        # mu
     ("solve", {}, [(28, 28), (28, 1)]),                     # Newton step
-], ids=["xtwx", "x_beta", "sigmoid", "solve"])
+], ids=["wgram", "xt_r", "x_beta", "sigmoid", "solve"])
 def test_newton_block_op_compiles(one_chip, jax_backend, op, meta, shapes):
     compiled = _compile(jax_backend._build(op, meta), one_chip, *shapes)
     mem = compiled.memory_analysis()
     # every operand and result of a block op fits one chip's 16 GB of HBM
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
+
+
+def test_weighted_gram_writes_no_n_by_d_array(one_chip, jax_backend):
+    """The Hessian's block op at HIGHEST, as it runs: the weighting multiply
+    fuses into the contraction's operand, so the chip neither writes nor
+    reads back an n x d product of w and X."""
+    import re
+
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in (HIGGS_BLOCK, HIGGS_COLUMN)]
+    compiled = jax.jit(jax_backend._lowering("wgram", {}, args)).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    rows, cols = HIGGS_BLOCK
+    assert not re.search(rf"= f32\[{rows},{cols}\]\S* fusion\(", entry), entry
+    assert "operand_precision={highest,highest}" in compiled.as_text()
 
 
 def test_lowered_newton_programs_compile(one_chip, jax_backend):
@@ -112,7 +130,10 @@ def test_lowered_newton_programs_compile(one_chip, jax_backend):
                        *shapes).memory_analysis()
         largest = max(largest, mem.argument_size_in_bytes
                       + mem.output_size_in_bytes + mem.temp_size_in_bytes)
-    assert 2e9 < largest < 16e9  # X^T (w X) reads X and C: 2.8 GB
+    # the largest reads X and one column (the weighted Gram: X and w, or
+    # X^T (mu - y)), 1.45 GB at the chip's padded layout; an n x d
+    # temporary beside X, such as w * X, would take it past 2.8 GB
+    assert 1.3e9 < largest < 2e9
 
 
 def test_lowered_cpals_programs_compile(one_chip, jax_backend):
