@@ -10,7 +10,7 @@ scheduling-plan cache.
 
 The cache is LRU (compiled executables hold device buffers on some runtimes,
 so the population must be bounded) and keeps hit/miss/eviction/compile-time
-counters that ``ArrayContext.loads`` and the bench-smoke artifact surface.
+counters that ``ArrayContext.loads`` surfaces.
 A single process-global instance (``GLOBAL_COMPILE_CACHE``) is shared by
 every jax/pallas backend instance: benchmark repeats and short-lived contexts
 re-use each other's compilations, exactly like ``jax.jit``'s own global

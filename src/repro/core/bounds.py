@@ -147,8 +147,8 @@ BOUNDS = {
 # run's measured transfer volume divides by them directly.  Each is the floor
 # a communication-optimal schedule attains in the paper's caching model (a
 # block is transmitted to a node at most once, §5.1) when the operation's
-# output blocks are forced onto a balanced hierarchical layout; the CI
-# bench-smoke ``linalg`` gate asserts measured ≤ constant × floor, turning
+# output blocks are forced onto a balanced hierarchical layout;
+# ``tests/test_linalg_ca.py`` asserts measured ≤ constant × floor, turning
 # every scheduler change into a checked comm-bound claim.
 
 def tsqr_lower_elements(d: int, k: int, q: int) -> float:

@@ -12,8 +12,8 @@ block-kernel subsystem):
 * ``pallas`` — the jax backend with ``matmul`` routed through the Pallas
   MXU kernel (``interpret=True`` off-TPU).
 * ``sim``    — metadata-only: tracks shapes and dispatch/transfer counts so
-  terabyte-scale graphs can be *scheduled* (load benchmarks) without
-  allocating data.  (No backend: there is nothing to execute.)
+  terabyte-scale graphs can be *scheduled* (placement and load studies)
+  without allocating data.  (No backend: there is nothing to execute.)
 
 Two dispatch modes share one interface:
 

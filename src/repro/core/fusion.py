@@ -11,7 +11,7 @@ Chain semantics live in ``graph_array.apply_chain``: the numpy backend
 interprets the chain step by step, while the jax/pallas backends
 (``repro.backend``) trace the same chain through ``jax.jit`` so a fused
 vertex executes as *one* compiled XLA fusion and one dispatch per block —
-the bench-smoke CI gate asserts the dispatch-count collapse.
+``tests/test_backend.py`` asserts the dispatch-count collapse.
 
 Already-``fused`` children (from a previous ``fuse_graph`` pass over a
 shared, not-yet-computed subgraph) are inlined and the walk *continues*

@@ -28,7 +28,7 @@ exact no-op: zero vertices, zero transfers, bit-identical blocks.
 implies: gather every block into one giant block on a single node, then
 slice each destination block out of it and scatter.  It uses the same
 vertex ops, so the moved-bytes advantage of locality-aware resharding is
-measured by the same load accounting (see ``benchmarks/bench_tensor.py``).
+measured by the same load accounting (see ``tests/test_reshard.py``).
 """
 from __future__ import annotations
 

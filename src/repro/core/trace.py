@@ -43,8 +43,7 @@ Overhead discipline: the buffer holds *raw tuples*; :class:`TraceEvent`
 objects (and the hot ``op`` event's args dict, including the
 binding-operand argmax) are materialized lazily at read time
 (``iter_events``/``of``/export), so the recording path is one tuple build +
-one deque append.  The traced/untraced wall ratio is CI-gated at ≤ 1.10x
-(``benchmarks.bench_trace``).
+one deque append.
 
 Viewing a trace in Perfetto
 ---------------------------
@@ -292,7 +291,7 @@ class FlightRecorder:
     Instrumented call sites hold a ``tracer``/``recorder`` attribute that is
     ``None`` when tracing is off; the recorder itself never mutates runtime
     state (clocks, RNG, stores), so tracing is bit- and clock-neutral by
-    construction (CI-gated in ``benchmarks.bench_trace``).
+    construction (``tests/test_obs.py``).
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):

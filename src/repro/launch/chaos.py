@@ -19,9 +19,9 @@ third run re-executes the chaos leg to check the determinism contract:
 same seed + same ChaosPlan ⇒ same chaos makespan, same retry counts, same
 speculation decisions.
 
-The fault-free vs degraded chaos-makespan ratio is the CI gate
-(``chaos-smoke``): 1 dead node + 2 stragglers (4x) must degrade the
-pipelined makespan by ≤ 50%.
+The fault-free vs degraded chaos-makespan ratio has a tier-1 floor
+(``tests/test_runtime_floors.py``): 1 dead node + 2 stragglers (4x) must
+degrade the pipelined makespan by ≤ 50%.
 """
 from __future__ import annotations
 

@@ -2,8 +2,8 @@
 
 One definition of the logreg Newton-iteration graph (the Fig. 15 workload)
 and the dense square matmul, shared by the launch driver
-(``repro.launch.blocks``), the benchmarks (``benchmarks.bench_micro``), and
-the pipeline tests — so all three exercise the *same* expression graph and a
+(``repro.launch.blocks``), the chaos and calibration drivers, and the
+pipeline tests — so all of them exercise the *same* expression graph and a
 change to the canonical workload lands everywhere at once.
 """
 from __future__ import annotations

@@ -12,7 +12,7 @@ forward substitution (L y = b) then blocked backward substitution
 
 Both record measured network elements against the ``core.bounds``
 moved-element floors via ``SchedStats.note_comm`` — the comm-bound ratio
-the CI bench-smoke gate enforces.
+``tests/test_linalg_ca.py`` bounds.
 """
 from __future__ import annotations
 

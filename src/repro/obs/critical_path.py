@@ -17,7 +17,7 @@ Each step covers the half-open window ``(pred.t1, cur.t1]`` exactly once
 (telescoping), and the head/tail windows cover ``[0, first.t0]`` and
 ``(last.t1, makespan]``, so the five buckets — ``compute``, ``transfer``,
 ``queue_stall``, ``retry``, ``eviction_stall`` — sum to the makespan to
-floating-point accuracy; the CI gate checks 100% ± 1%.  Gap time inside a
+floating-point accuracy; the tests check 100% ± 1%.  Gap time inside a
 window is charged in priority order: lane stall slices (eviction/fault-in
 backpressure) first, then the op's recorded retry backoff, then wire time,
 then residual ``queue_stall`` (dependency or channel wait).

@@ -5,7 +5,7 @@ The runtime grew four ad-hoc stats objects (``SchedStats``, ``BackendStats``,
 ``ChaosStats``, ``MemStats``) plus the cluster load summary, each with its
 own ``as_dict``/``snapshot`` spelling, and ``ArrayContext.loads()`` glued
 them together inline — so every PR that touched a stats object silently
-reshaped the ``loads()`` schema that ``check_smoke.py`` gates on.  The
+reshaped the ``loads()`` schema that its readers parse.  The
 registry inverts that: stats sources register as named *providers* and
 ``snapshot()`` merges them in registration order, so the key set is a
 function of the registered features alone (golden-tested per feature set in

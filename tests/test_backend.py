@@ -15,9 +15,16 @@ Covers the backend subsystem end to end:
   compiled dispatch per block on the jax backend;
 * the no-host-round-trip property of device-resident execution (h2d/d2h
   counters flat across op execution);
-* fault-tolerance lineage replay on the compiled backend.
+* fault-tolerance lineage replay on the compiled backend;
+* placement over four devices: node i's blocks on ``jax.devices()[i]``.
 """
 from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import pytest
@@ -538,3 +545,77 @@ def test_sim_mode_has_no_backend():
     assert ex.backend is None
     with pytest.raises(ValueError):
         Executor(mode="bogus")
+
+
+def test_four_chip_layout_on_virtual_devices():
+    """Four nodes on four (virtual CPU) devices: a Newton fit over 32 row
+    blocks and a 2 x 2 node-grid matmul on the jax and pallas backends keep
+    every block of node i on ``jax.devices()[i]``, spread over all four
+    nodes, move operands between devices, and match numpy."""
+    code = """
+        import json
+        import jax
+        import numpy as np
+        from repro.core import ArrayContext, ClusterSpec
+        from repro.glm import LogisticRegression, overlapping_gaussians
+
+        def layout(ctx):
+            ex, devices = ctx.executor, jax.devices()
+            misplaced, nodes = 0, set()
+            for vid, value in ex.store.items():
+                if value is None:
+                    continue
+                node = ex.block_home[vid][0]
+                nodes.add(node)
+                misplaced += (not isinstance(value, jax.Array)
+                              or value.devices() != {devices[node]})
+            return {"misplaced": misplaced, "nodes": sorted(nodes),
+                    "moves": ctx.loads()["backend_device_moves"]}
+
+        def rel_err(got, ref):
+            return float(np.linalg.norm(got - ref) / np.linalg.norm(ref))
+
+        out = {}
+        X, y = overlapping_gaussians(32_000, d=28, seed=1, sep=0.25)
+        X, y = X.astype(np.float32), y.astype(np.float32)
+        # gc stays off, so the store keeps every block the fit made
+        ctx = ArrayContext(cluster=ClusterSpec(4, 8), node_grid=(4, 1),
+                           backend="jax", pipeline=True, plan_cache=True)
+        model = LogisticRegression(ctx, solver="newton", reg=1e-6,
+                                   max_iter=10)
+        model.fit_numpy(X, y, row_blocks=32)
+        X64, y64 = X.astype(np.float64), y.astype(np.float64).ravel()
+        ref = np.zeros(X.shape[1])
+        for _ in range(25):
+            mu = 1.0 / (1.0 + np.exp(-(X64 @ ref)))
+            g = X64.T @ (mu - y64) + 1e-6 * ref
+            H = X64.T @ (X64 * (mu * (1.0 - mu))[:, None]) + 1e-6 * np.eye(28)
+            ref = ref - np.linalg.solve(H, g)
+        out["newton"] = {"rel_err": rel_err(model.beta.ravel(), ref),
+                         **layout(ctx)}
+
+        for backend in ("jax", "pallas"):
+            ctx = ArrayContext(cluster=ClusterSpec(4, 4), node_grid=(2, 2),
+                               backend=backend, seed=1)
+            A = ctx.random((512, 512), grid=(4, 4))
+            B = ctx.random((512, 512), grid=(4, 4))
+            C = (A @ B).compute().wait()
+            ref = (A.to_numpy().astype(np.float64)
+                   @ B.to_numpy().astype(np.float64))
+            out[backend] = {"rel_err": rel_err(C.to_numpy(), ref), **layout(ctx)}
+        print("RESULT", json.dumps(out))
+    """
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, env=env, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    (line,) = [ln for ln in r.stdout.splitlines() if ln.startswith("RESULT ")]
+    res = json.loads(line.split(" ", 1)[1])
+    # float32 blocks against float64 numpy
+    assert res["newton"]["rel_err"] <= 1e-6
+    assert res["jax"]["rel_err"] <= 1e-4 and res["pallas"]["rel_err"] <= 1e-4
+    for run in res.values():
+        assert run["misplaced"] == 0
+        assert run["nodes"] == [0, 1, 2, 3]
+        assert run["moves"] > 0

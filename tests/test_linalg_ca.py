@@ -142,7 +142,7 @@ class TestRsvdParity:
 
 class TestCommRatio:
     """Measured moved elements vs the bounds.py floors (the CI-gated
-    metric), on deterministic sim clusters at the bench-smoke ceilings."""
+    metric), on deterministic sim clusters."""
 
     def test_cholesky_ratio_within_gate(self):
         ctx = make_ctx(backend="sim")

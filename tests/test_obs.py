@@ -47,8 +47,8 @@ def small_workload(ctx, n=128, d=16, q=8):
 
 # -- golden loads() schema ----------------------------------------------------
 # The exact key *sequence* of ctx.loads() per feature set.  These lists are
-# the contract downstream consumers (benchmarks/check_smoke.py, launch
-# drivers, notebook dashboards) parse — extending a stats object must extend
+# the contract downstream consumers (the chip harness under ``bench/``,
+# launch drivers, notebook dashboards) parse — extending a stats object must extend
 # the matching list here, in provider order.
 
 SUMMARY_KEYS = [

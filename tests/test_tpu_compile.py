@@ -1,8 +1,8 @@
 """Compiles of the block runtime's main path for a described TPU v5e chip.
 
 Nothing here runs on a chip: each test compiles one kernel or block op for
-the first chip of a described ``v5e:2x2`` topology, at the shapes the chip
-smoke test (``chip_smoke.py``) runs, and fails where the chip's compiler
+the first chip of a described ``v5e:2x2`` topology, at the shapes the
+benchmark cells under ``bench/`` run, and fails where the chip's compiler
 would refuse it.  The topology is described inside a fixture, never at
 import, so every test worker collects the same tests.
 """
